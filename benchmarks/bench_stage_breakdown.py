@@ -167,7 +167,7 @@ def run_kernels(fast: bool = True, out_path: str = None) -> dict:
         plan, hi, lo, sl, statics, tables, sig = _decode_bucket_operands(
             ds, length
         )
-        args = (hi, lo, sl, plan.tables, plan.lut, plan.basis)
+        args = (hi, lo, sl, plan.tables, plan.lut, plan.rscale)
 
         # staged XLA (the unfused engine arm)
         t_xla, ref = _time(
@@ -192,7 +192,7 @@ def run_kernels(fast: bool = True, out_path: str = None) -> dict:
             )
             return kops.idct_dequant(
                 syms.reshape(statics["num_windows"], statics["e"]),
-                plan.tables.quant, n=statics["n"], basis=plan.basis,
+                plan.lut, plan.rscale, n=statics["n"],
             )
 
         t_staged_k, _ = _time(staged_kernels, hi, lo, sl)
@@ -223,14 +223,16 @@ def run_kernels(fast: bool = True, out_path: str = None) -> dict:
             functools.partial(_encode_bucket, **enc_statics), *enc_args
         )
         t_enc_fused, egot = _time(
-            functools.partial(_encode_bucket_kernels, **enc_statics),
-            *enc_args[:2], eplan.tables, eplan.basis,
+            functools.partial(
+                _encode_bucket_kernels, l_max=cfg.l_max, **enc_statics
+            ),
+            *enc_args,
         )
         for a, b in zip(eref, egot):
             assert bool(jnp.all(a == b)), ds
         enc_jaxpr = jax.make_jaxpr(functools.partial(
-            _encode_bucket_kernels_math, **enc_statics
-        ))(*enc_args[:2], eplan.tables, eplan.basis)
+            _encode_bucket_kernels_math, l_max=cfg.l_max, **enc_statics
+        ))(*enc_args)
 
         rec = {
             "decode": {

@@ -140,13 +140,12 @@ def decode_gbps(container, tables, trials=5, decoder=None):
         num_windows=p2(max(container.num_windows, 1)),
         n=plan.n, e=plan.e, use_kernels=dec.use_kernels,
     )
-    _decode_bucket(hi, lo, sl, plan.tables, plan.basis, **kw).block_until_ready()
+    args = (hi, lo, sl, plan.tables, plan.lut, plan.rscale)
+    _decode_bucket(*args, **kw).block_until_ready()
     times = []
     for _ in range(trials):
         t0 = time.perf_counter()
-        _decode_bucket(
-            hi, lo, sl, plan.tables, plan.basis, **kw
-        ).block_until_ready()
+        _decode_bucket(*args, **kw).block_until_ready()
         times.append(time.perf_counter() - t0)
     out_bytes = container.signal_length * 4
     return [out_bytes / t / 1e9 for t in times]

@@ -25,6 +25,9 @@ def main() -> None:
                     help="comma-separated bench names (e.g. rd,roofline)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_compression_integration,
         bench_ne_sweep,

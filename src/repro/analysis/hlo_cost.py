@@ -479,20 +479,11 @@ def _pallas_block_bytes(grid_mapping, eqn) -> Tuple[float, float]:
             pass  # symbolic grid dim: count once
     per_step = 0.0
     for bm in getattr(grid_mapping, "block_mappings", ()) or ():
-        block = getattr(bm, "block_shape", None)
-        sd = getattr(bm, "array_shape_dtype", None)
-        if block is None or sd is None:
-            continue
+        block = bm.ref_aval  # the block as one grid step sees it
         elems = 1
-        for d in block:
-            try:
-                elems *= max(int(d), 1)
-            except TypeError:
-                pass  # squeezed/mapped dims contribute one row
-        try:
-            per_step += float(elems * sd.dtype.itemsize)
-        except AttributeError:
-            continue
+        for d in block.shape:
+            elems *= max(int(d), 1)
+        per_step += float(elems * block.dtype.itemsize)
     if per_step == 0.0:
         # no usable block mappings (e.g. an older pallas): fall back to
         # the call's operand + output avals, moved once
@@ -549,7 +540,7 @@ def _jaxpr_cost(jaxpr, state: _JaxprState) -> Cost:
                 total = total + max(costs, key=lambda c: c.flops)
             continue
 
-        if prim in ("pjit", "closed_call", "core_call", "remat_call",
+        if prim in ("jit", "pjit", "closed_call", "core_call", "remat_call",
                     "checkpoint", "remat", "custom_jvp_call",
                     "custom_vjp_call", "custom_vjp_call_jaxpr"):
             body = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

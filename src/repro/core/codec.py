@@ -27,7 +27,9 @@ from repro.core.quantize import (
     dequantize,
     expand_coded_stream,
     predict_levels,
+    quant_grid,
     quantize,
+    recon_scale,
     unpredict_levels,
 )
 
@@ -136,7 +138,9 @@ def decode(container: Container, tables: DomainTables) -> np.ndarray:
             jnp.asarray(grid, jnp.uint32), jnp.asarray(seg), pred_id, bands
         )
     coeffs = dequantize(coeffs_q, tables.quant)
-    windows = dct.inverse_dct(coeffs, container.n)
+    windows = dct.inverse_dct(
+        coeffs, container.n, scale=recon_scale(tables.quant)
+    )
     return np.asarray(dct.unwindow_signal(windows, container.signal_length))
 
 
@@ -215,7 +219,10 @@ def _decode_device(
             l_max=l_max, max_symlen=max_symlen, num_symbols=num_symbols,
         )
         coeffs_q = syms.reshape(num_windows, e)
-        windows = kops.idct_dequant(coeffs_q, tables.quant, n=n)
+        lut, _ = quant_grid(tables.quant)
+        windows = kops.idct_dequant(
+            coeffs_q, lut, recon_scale(tables.quant), n=n
+        )
     else:
         syms = symlen.unpack_symlen(
             hi, lo, sl,
@@ -225,7 +232,7 @@ def _decode_device(
         )
         coeffs_q = syms.reshape(num_windows, e)
         coeffs = dequantize(coeffs_q, tables.quant)
-        windows = dct.inverse_dct(coeffs, n)
+        windows = dct.inverse_dct(coeffs, n, scale=recon_scale(tables.quant))
     return dct.unwindow_signal(windows, signal_length)
 
 

@@ -31,6 +31,8 @@ __all__ = [
     "build_quant_table",
     "quantize",
     "dequantize",
+    "level_thresholds",
+    "recon_scale",
     "predict_levels",
     "unpredict_levels",
     "expand_coded_stream",
@@ -49,15 +51,37 @@ class QuantTable:
       scale: float32[E] — per-bin clipped-percentile maximum (A0 / A1).
       mu:    float32[] — companding strength (zone 0).
       alpha1: float32[] — deadzone ratio (zone 1).
+      thresholds: float32[2, E, 128] — the level boundaries the quantizer
+        compares against (:func:`level_thresholds`).
+      grid: float32[E, 256] — the reconstruction value of every level in
+        every bin (:func:`level_grid`), what dequantization selects from.
+
+    ``thresholds`` and ``grid`` are derived from the four fields above on
+    the host when not given, so they are the same bits on every backend.
     """
 
     zone: jnp.ndarray
     scale: jnp.ndarray
     mu: jnp.ndarray
     alpha1: jnp.ndarray
+    thresholds: jnp.ndarray = None
+    grid: jnp.ndarray = None
+
+    def __post_init__(self):
+        for name, derive in (
+            ("thresholds", level_thresholds), ("grid", level_grid)
+        ):
+            if getattr(self, name) is None:
+                object.__setattr__(self, name, jnp.asarray(derive(
+                    np.asarray(self.zone), np.asarray(self.scale),
+                    float(self.mu), float(self.alpha1),
+                )))
 
     def tree_flatten(self):
-        return (self.zone, self.scale, self.mu, self.alpha1), None
+        return (
+            self.zone, self.scale, self.mu, self.alpha1, self.thresholds,
+            self.grid,
+        ), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -67,6 +91,73 @@ class QuantTable:
     @property
     def num_coeffs(self) -> int:
         return self.zone.shape[0]
+
+
+def level_thresholds(
+    zone: np.ndarray, scale: np.ndarray, mu: float, alpha1: float
+) -> np.ndarray:
+    """The quantizer's level boundaries, f32[2, E, 128], computed on the host.
+
+    ``[0, k]`` holds the ascending coefficient values at which bin k's level
+    steps up from 128 (``c >= t`` counts one step), ``[1, k]`` the same for
+    ``-c`` stepping down; unreachable steps are +inf.  They are the exact
+    real-valued boundaries of Eqs. 2-3 (mu-law rounding in zone 0, the
+    deadzone linear quantizer in zone 1, clipping at A), evaluated in
+    float64 and rounded *up* to f32, so the comparison ``c >= t`` in f32 is
+    exact.  Comparisons are the same bits on every backend, unlike a
+    ``log1p`` or a division, so a coefficient quantizes to the same level on
+    the CPU, the TPU and inside a kernel.
+    """
+    zone = np.asarray(zone).ravel()
+    a = np.asarray(scale, np.float32).ravel().astype(np.float64)
+    e = zone.shape[0]
+    thr = np.full((2, e, 128), np.inf)
+    tiny = float(np.finfo(np.float32).tiny)  # c > 0 (normal floats)
+    lmu = np.log1p(mu)
+    steps = {0: np.arange(1, 127) - 0.5, 1: np.arange(1, 128) - 0.5}
+    levels = {0: 126.0, 1: 127.0}
+    for k in range(e):
+        if zone[k] == 0:
+            for side in (0, 1):
+                thr[side, k, 0] = tiny
+                q = steps[side] / levels[side]
+                thr[side, k, 1:1 + q.size] = a[k] * np.expm1(q * lmu) / mu
+        elif zone[k] == 1:
+            d1 = float(np.float32(alpha1) * np.float32(a[k]))
+            denom = max(a[k] - d1, 1e-12)
+            for side in (0, 1):
+                thr[side, k, 0] = np.nextafter(np.float32(d1), np.inf)
+                q = steps[side] / levels[side]
+                thr[side, k, 1:1 + q.size] = d1 + q * denom
+        thr[:, k][thr[:, k] > a[k]] = np.inf  # |c| clips to A
+    t32 = thr.astype(np.float32)
+    low = t32.astype(np.float64) < thr
+    t32[low] = np.nextafter(t32[low], np.float32(np.inf))
+    return t32
+
+
+def level_grid(
+    zone: np.ndarray, scale: np.ndarray, mu: float, alpha1: float
+) -> np.ndarray:
+    """Every level's reconstruction per bin, f32[E, 256], on the host.
+
+    The midpoint of each quantization cell (Eqs. 2-3 inverted: mu-law
+    expansion in zone 0, the deadzone linear map in zone 1, zero in zone 2
+    and on the zero bin), evaluated in float64 and rounded once to f32 —
+    a ``expm1`` on the device would round differently per backend."""
+    zone = np.asarray(zone).ravel()[:, None]
+    a = np.asarray(scale, np.float32).ravel().astype(np.float64)[:, None]
+    lvl = np.arange(256, dtype=np.float64)[None, :]
+    pos, neg = lvl > _ZERO_BIN, lvl < _ZERO_BIN
+    q = np.clip(np.where(pos, (lvl - 129.0) / 126.0, (127.0 - lvl) / 127.0),
+                0.0, 1.0)
+    mag0 = a * np.expm1(q * np.log1p(mu)) / mu
+    d1 = (np.float32(alpha1) * a.astype(np.float32)).astype(np.float64)
+    mag1 = d1 + q * (a - d1)
+    sign = np.where(pos, 1.0, np.where(neg, -1.0, 0.0))
+    grid = np.where(zone == 0, sign * mag0, np.where(zone == 1, sign * mag1,
+                                                     0.0))
+    return grid.astype(np.float32)
 
 
 def build_quant_table(
@@ -107,80 +198,31 @@ def build_quant_table(
     )
 
 
-def _mulaw_compress(c_abs: jnp.ndarray, a0: jnp.ndarray, mu: jnp.ndarray):
-    """Eq. 2: q = ln(1 + mu*|c|/A0) / ln(1 + mu), |c| clipped to A0."""
-    x = jnp.minimum(c_abs / a0, 1.0)
-    return jnp.log1p(mu * x) / jnp.log1p(mu)
-
-
-def _mulaw_expand(q: jnp.ndarray, a0: jnp.ndarray, mu: jnp.ndarray):
-    return a0 * (jnp.expm1(q * jnp.log1p(mu)) / mu)
-
-
 def quantize(coeffs: jnp.ndarray, table: QuantTable) -> jnp.ndarray:
-    """Map float coefficients [..., E] to uint8 levels via the 3-zone table."""
-    c = coeffs.astype(jnp.float32)
-    a = table.scale
-    mu = table.mu
-    sign_pos = c > 0
+    """Map float coefficients [..., E] to uint8 levels via the 3-zone table.
 
-    # --- zone 0: mu-law companding -------------------------------------
-    q01 = _mulaw_compress(jnp.abs(c), a, mu)
-    lvl0 = jnp.where(
-        sign_pos,
-        129.0 + jnp.round(q01 * 126.0),
-        127.0 - jnp.round(q01 * 127.0),
-    )
-    # exact zeros land on the zero bin
-    lvl0 = jnp.where(c == 0, _ZERO_BIN, lvl0)
+    The level is ``128 + #(c >= up-steps) - #(-c >= down-steps)`` against
+    the table's :func:`level_thresholds` — comparisons only, so the same
+    coefficient gets the same level on every backend."""
+    return quantize_to(coeffs, table.thresholds)
 
-    # --- zone 1: linear deadzone (Eq. 3) --------------------------------
-    d1 = table.alpha1 * a
-    denom = jnp.maximum(a - d1, 1e-12)
-    c_clip = jnp.clip(c, -a, a)
-    mag = jnp.abs(c_clip)
-    lvl1_pos = 129.0 + jnp.floor((c_clip - d1) / denom * 126.0 + 0.5)
-    lvl1_neg = 127.0 - jnp.floor((mag - d1) / denom * 127.0 + 0.5)
-    lvl1 = jnp.where(
-        c_clip > d1, lvl1_pos, jnp.where(c_clip < -d1, lvl1_neg, _ZERO_BIN)
-    )
 
-    # --- zone 2: aggressive zeroing -------------------------------------
-    lvl2 = jnp.full_like(c, _ZERO_BIN)
-
-    lvl = jnp.where(
-        table.zone == 0, lvl0, jnp.where(table.zone == 1, lvl1, lvl2)
-    )
-    return jnp.clip(lvl, 0.0, 255.0).astype(jnp.uint8)
+def quantize_to(coeffs: jnp.ndarray, thr: jnp.ndarray) -> jnp.ndarray:
+    """:func:`quantize` against the thresholds alone (what a kernel holds)."""
+    c = coeffs.astype(jnp.float32)[..., None]
+    up = jnp.sum(c >= thr[0], axis=-1, dtype=jnp.int32)
+    down = jnp.sum(-c >= thr[1], axis=-1, dtype=jnp.int32)
+    return (128 + up - down).astype(jnp.uint8)
 
 
 def dequantize(levels: jnp.ndarray, table: QuantTable) -> jnp.ndarray:
     """Inverse 3-zone mapping: uint8 levels [..., E] -> float32 coefficients.
 
-    Uses the midpoint reconstruction of each quantization cell.
-    """
-    lvl = levels.astype(jnp.float32)
-    a = table.scale
-    mu = table.mu
-    pos = lvl > _ZERO_BIN
-    neg = lvl < _ZERO_BIN
-
-    # zone 0 inverse mu-law
-    q01 = jnp.where(pos, (lvl - 129.0) / 126.0, (127.0 - lvl) / 127.0)
-    mag0 = _mulaw_expand(jnp.clip(q01, 0.0, 1.0), a, mu)
-    c0 = jnp.where(pos, mag0, -mag0)
-    c0 = jnp.where(lvl == _ZERO_BIN, 0.0, c0)
-
-    # zone 1 inverse linear deadzone
-    d1 = table.alpha1 * a
-    span = a - d1
-    mag1 = jnp.where(
-        pos, d1 + (lvl - 129.0) / 126.0 * span, d1 + (127.0 - lvl) / 127.0 * span
-    )
-    c1 = jnp.where(pos, mag1, jnp.where(neg, -mag1, 0.0))
-
-    c = jnp.where(table.zone == 0, c0, jnp.where(table.zone == 1, c1, 0.0))
-    return c
+    The midpoint reconstruction of each quantization cell, selected from
+    the table's host-computed :func:`level_grid` (so the same bits on every
+    backend)."""
+    e = table.num_coeffs
+    return table.grid[jnp.arange(e, dtype=jnp.int32), levels.astype(jnp.int32)]
 
 
 # ---------------------------------------------------------------------------
@@ -286,18 +328,19 @@ def expand_coded_stream(
 
 
 def quant_grid(table: QuantTable) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """All 256 reconstruction values per bin: [E, 256] (for LUT-style dequant).
+    """All 256 reconstruction values per bin: ([E, 256] LUT, the levels).
 
-    This is the dequantization table materialized — usable as a gather-free
-    one-hot matmul operand by fused decode kernels, and by tests bounding
-    the per-cell quantization error.  ``dequantize`` broadcasts over
-    leading axes with the bin axis last, so the whole grid is one call on a
-    [256, E] level matrix (the old per-bin ``vmap`` sliced the table with a
-    traced index and could never actually trace).
+    The dequantization table the engines and kernels select from (exact
+    selection, gather-free in the kernels) and tests bound the per-cell
+    quantization error with: the table's host-computed ``grid``.
     """
-    levels = jnp.arange(256, dtype=jnp.uint8)  # [256]
-    e = table.num_coeffs
-    grid = dequantize(
-        jnp.broadcast_to(levels[:, None], (256, e)), table
-    )  # [256, E]: column k reconstructs every level under bin k
-    return grid.T, levels
+    return table.grid, jnp.arange(256, dtype=jnp.uint8)
+
+
+def recon_scale(table: QuantTable) -> jnp.ndarray:
+    """A bound on every reconstructed |coefficient| of ``table`` (the
+    largest per-bin scale A): what the backend-exact inverse DCT splits the
+    dequantized coefficients against (``dct.inverse_dct(..., scale=)``), so
+    the host decoder, the engines' LUT path and the decode megakernel all
+    cut the same slices."""
+    return jnp.max(table.scale)

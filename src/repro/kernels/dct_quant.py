@@ -10,13 +10,12 @@ the accelerator matters.
     f32[W_blk, N] @ dct_basis[N, E]  --(MXU)-->  coeffs f32[W_blk, E]
     coeffs --(3-zone quantize, elementwise)-->  levels int32[W_blk, E]
 
-Two quantization arms share the tile: the default inlines the 3-zone math
-(hand-written for the VPU; may differ from the reference by one level at a
-cell boundary for a ~1e-3 fraction of samples), while ``exact=True``
-traces ``repro.core.quantize.quantize`` itself inside the kernel — the
-bit-parity arm the fused encode kernel (``repro.kernels.encode_fused``,
-which extends this tile all the way into Huffman codeword emission) is
-built on.
+Two arms share the tile: the default runs one f32 matmul and inlines the
+3-zone math (hand-written for the VPU; may differ from the reference by one
+level at a cell boundary for a ~1e-3 fraction of samples), while
+``exact=True`` traces the reference itself inside the kernel — the
+backend-exact split DCT (``repro.core.dct``) and the threshold quantizer
+(``repro.core.quantize.quantize``) — for bit parity with the XLA path.
 """
 from __future__ import annotations
 
@@ -26,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.quantize import QuantTable, quantize as _quantize_exact
+from repro.core import dct as _dct
+from repro.core.quantize import QuantTable, quantize_to
 
 __all__ = ["dct_quant"]
 
@@ -38,25 +38,27 @@ def _kernel(
     windows_ref,  # f32[BW, N]
     zone_ref,  # int32[E]
     scale_ref,  # f32[E]
-    basis_ref,  # f32[N, E]
+    thr_ref,  # f32[2, E, 128] level thresholds
+    basis_ref,  # f32[N, E] (approximate arm) / f32[3, N, E] slices (exact)
     mu_ref,  # f32[1]
     alpha1_ref,  # f32[1]
     out_ref,  # int32[BW, E]
     *,
     exact: bool = False,
+    basis_scale: float = 1.0,
 ):
-    c = jnp.dot(
-        windows_ref[...], basis_ref[...], preferred_element_type=jnp.float32
-    )  # [BW, E]
     if exact:
-        table = QuantTable(
-            zone=zone_ref[...],
-            scale=scale_ref[...],
-            mu=mu_ref[0],
-            alpha1=alpha1_ref[0],
-        )
-        out_ref[...] = _quantize_exact(c, table).astype(jnp.int32)
+        x = windows_ref[...]
+        s, inv = _dct.pow2_above(jnp.max(jnp.abs(x), axis=-1, keepdims=True))
+        b = basis_ref[...]
+        c = _dct.split_dot(_dct.split3(x, inv), (b[0], b[1], b[2]))
+        c = c * basis_scale * s
+        out_ref[...] = quantize_to(c, thr_ref[...]).astype(jnp.int32)
         return
+    c = jnp.dot(
+        windows_ref[...], basis_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )  # [BW, E]
     zone = zone_ref[...]
     a = scale_ref[...]
     mu = mu_ref[0]
@@ -99,47 +101,53 @@ def _kernel(
 )
 def dct_quant(
     windows: jnp.ndarray,  # f32[W, N]
-    zone: jnp.ndarray,  # int32[E]
-    scale: jnp.ndarray,  # f32[E]
-    basis: jnp.ndarray,  # f32[N, E] (dct_basis)
-    mu: jnp.ndarray,
-    alpha1: jnp.ndarray,
+    quant: QuantTable,
+    basis: jnp.ndarray,  # f32[N, E] (dct_basis; approximate arm)
     *,
     e: int,
     block_windows: int = BLOCK_WINDOWS,
-    interpret: bool = True,
+    interpret: bool,
     exact: bool = False,
 ) -> jnp.ndarray:
     """Fused forward DCT + 3-zone quantize: [W, N] samples -> [W, E] levels.
 
-    ``exact=True`` selects the reference-parity quantization arm (see the
-    module docstring)."""
+    ``exact=True`` selects the reference-parity arm (see the module
+    docstring); it takes the basis slices from ``core.dct`` itself."""
     w, n = windows.shape
     num_blocks = -(-w // block_windows)
     wp = num_blocks * block_windows
     if wp != w:
         windows = jnp.pad(windows, ((0, wp - w), (0, 0)))
+    basis_scale = 1.0
+    if exact:
+        b_sl, basis_scale = _dct.dct_basis_slices(n, e)
+        basis = jnp.asarray(b_sl)
+
+    def rep(shape):
+        return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, exact=exact),
+        functools.partial(_kernel, exact=exact, basis_scale=basis_scale),
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec((block_windows, n), lambda i: (i, 0)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-            pl.BlockSpec((n, e), lambda i: (0, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            rep((e,)),
+            rep((e,)),
+            rep((2, e, 128)),
+            rep(basis.shape),
+            rep((1,)),
+            rep((1,)),
         ],
         out_specs=pl.BlockSpec((block_windows, e), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp, e), jnp.int32),
         interpret=interpret,
     )(
         windows,
-        zone,
-        scale,
+        quant.zone,
+        quant.scale,
+        quant.thresholds,
         basis,
-        jnp.reshape(mu.astype(jnp.float32), (1,)),
-        jnp.reshape(alpha1.astype(jnp.float32), (1,)),
+        jnp.reshape(quant.mu.astype(jnp.float32), (1,)),
+        jnp.reshape(quant.alpha1.astype(jnp.float32), (1,)),
     )
     return out[:w]

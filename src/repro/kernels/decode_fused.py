@@ -9,124 +9,167 @@ has two *phases* (the coarse/fine fusion of Tian et al., "Revisiting
 Huffman Coding", and cuSZ+'s fused gap-array design):
 
   phase 1 (steps ``0 .. num_word_blocks``): per word block, the arithmetic
-    canonical Huffman decode fills a VMEM tile, a VMEM-resident exclusive
-    prefix-scan of the symlen sidecar assigns output offsets (running base
-    in SMEM scratch across the sequential TPU grid), and the cooperative
-    word-major store compacts symbols into a dense VMEM *scratch* stream —
-    the padded tile and the dense symbol stream never touch HBM;
+    canonical Huffman decode fills a VMEM tile, a running scalar offset
+    over the SMEM symlen sidecar (the exclusive prefix scan, carried across
+    the sequential TPU grid in SMEM scratch) assigns output positions, and
+    the cooperative word-major store compacts symbols into a lane-dense
+    ``[rows, 128]`` VMEM *scratch* stream — the padded tile and the dense
+    symbol stream never touch HBM;
   phase 2 (remaining steps): per window block, levels are read back out of
-    the dense scratch, dequantized by *exact selection* from the
-    materialized 256-level reconstruction LUT
-    (``repro.core.quantize.quant_grid`` — precomputed once per decode
-    plan, so the fused path and the XLA reference path consume literally
-    the same float values and stay bit-identical under jit), and
-    multiplied against the iDCT basis on the MXU into the output block.
+    the dense scratch, dequantized by *exact selection* from the plan's
+    256-level reconstruction LUT (``repro.core.quantize.quant_grid``) and
+    transformed by the backend-exact split iDCT (``repro.core.dct``) into
+    the output block.
 
-VMEM budget per grid step (BLOCK_WORDS=512, BLOCK_WINDOWS=256, MS<=64,
-N, E <= 128):
-  word block in: hi/lo/symlen    3 * 512 * 4 B          =    6 KiB
-  decode tables                                         <    3 KiB
-  dequant LUT                    128 * 256 * 4 B        =  128 KiB
-  tile scratch                   64 * 512 * 4 B         =  128 KiB
-  dense symbol scratch           4 B * (Wn * E + MS)    = data-dependent
-  idct basis                     128 * 128 * 4 B        =   64 KiB
-  out window block               256 * 128 * 4 B        =  128 KiB
-The dense scratch (and the resident output) scale with the bucket, so a
-1M-symbol bucket costs ~4 MiB of VMEM — inside a v5e core's ~16 MiB, and
-``repro.kernels.ops`` guards the int32 offset range long before VMEM does.
+The window block works on the stream's own layout: a stream row of
+``Lw = lcm(E, 128)`` lanes holds ``Lw / E`` whole windows, so a
+block-diagonal basis ``[Lw, (Lw/E) * N]`` turns a block of stream rows
+straight into output samples with no relayout (``Lw > 128`` rows are read
+as ``Lw / 128`` strided 128-lane row sets).  The LUT is selected already
+split (three 10-bit slice fields per int32 entry, tiled to the lane
+pattern), and the six bf16 slice products are exact integers, so the
+output is bit-identical to the XLA bucket arm — and to itself on every
+backend.
 
-Like every kernel in this package the megakernel is validated in interpret
-mode (CPU); ``core.symlen.compact_padded_scatter`` + the staged kernels
-remain the interpret-mode oracle it is tested against.
+VMEM per grid step (BLOCK_WORDS=1024, BLOCK_WINDOWS=256, N, E <= 128):
+  word block in: hi/lo               2 * 1024 * 4 B (x2)    =   16 KiB
+  tiles (slot- and word-major)       2 * 128 * 1024 * 4 B   =    1 MiB
+  split LUT                          (Lw/128) * 256 * 128*4 = 128-384 KiB
+  block-diagonal basis slices        3 * Lw * (Lw/E) * N * 2 B
+                                     (192 KiB at E=32, 4.5 MiB at E=6)
+  out window block                   4 B * BLOCK_WINDOWS * N (x2)
+  dense symbol scratch               4 B * (num_windows * E + 2 rows)
+The dense scratch scales with the bucket and is what limits it.  Compiled
+for a TPU v5e (128 MiB of VMEM per core, the kernel's scoped limit raised
+to ``ops.VMEM_LIMIT_BYTES`` = 96 MiB), buckets of up to about 24 Mi symbols
+fit at every paper-domain width (N = 32, E = 6/8/16/32), a few MiB less
+when the bucket has few word blocks.  ``repro.kernels.ops`` admits what
+``ops.decode_vmem_bytes`` counts within an 88 MiB budget (about 22 Mi
+symbols) and refuses a bigger bucket with a typed
+:class:`~repro.kernels.ops.KernelLimitError`; paging the scratch through
+HBM is future work.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import dct
 from repro.core.quantize import expand_coded_stream, unpredict_levels
-from repro.kernels.huffman_decode import BLOCK_WORDS, decode_block_to_dense
+from repro.kernels.huffman_decode import (
+    BLOCK_WORDS,
+    LANES,
+    decode_block_to_dense,
+    dense_rows,
+    table_operands,
+    table_specs,
+    word_block,
+    word_operands,
+)
 
-__all__ = ["decode_fused", "lut_dequant", "BLOCK_WINDOWS"]
+__all__ = [
+    "decode_fused",
+    "BLOCK_WINDOWS",
+    "row_lanes",
+    "window_align",
+]
 
 BLOCK_WINDOWS = 256
+_SLICE_FIELD = 10  # bits per split-LUT slice field: values in [0, 512]
 
 _TRIVIAL = (0, 0, False)  # no predictor, no zero planes: the v1/v2 stream
 
 
-def lut_dequant(levels: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
-    """Exact-selection dequant: levels int32[W, E], lut f32[E, 256] ->
-    coeffs f32[W, E] with ``coeffs[w, k] = lut[k, levels[w, k]]``.
+def row_lanes(e: int) -> int:
+    """``Lw = lcm(E, 128)``: the narrowest stream row holding whole windows."""
+    return LANES * e // math.gcd(e, LANES)
 
-    A masked sum over the 256 level values — each element selects exactly
-    one LUT entry, so the result is bit-identical to a gather while
-    lowering to pure vector compares/selects (no per-element VMEM gather,
-    which TPUs lack).  Both the fused kernel and the XLA bucket path
-    dequantize through the same plan-resident LUT, which is what makes
-    their float outputs identical.
-    """
 
-    def step(v, acc):
-        return acc + jnp.where(levels == v, lut[:, v][None, :], 0.0)
+def window_align(e: int) -> int:
+    """Window-block granularity: 8 stream rows of ``Lw`` lanes."""
+    return 8 * row_lanes(e) // e
 
-    init = jnp.zeros(levels.shape, jnp.float32)
-    return jax.lax.fori_loop(0, 256, step, init)
+
+def _split_lut(lut, rscale, e: int):
+    """The LUT split against ``rscale`` (exactly as ``dct.inverse_dct``
+    splits the coefficients it selects), packed three 10-bit fields per
+    int32 and tiled to the 128-lane pattern of each row phase:
+    int32[Lw/128, 256, 128]."""
+    _, inv = dct.pow2_above(jnp.asarray(rscale, jnp.float32))
+    sl = dct.split3(lut, inv)  # 3 x f32[E, 256], integers in [-256, 256]
+    packed = sum(
+        (s.astype(jnp.int32) + 256) << (_SLICE_FIELD * i)
+        for i, s in enumerate(sl)
+    )  # [E, 256]
+    lw = row_lanes(e)
+    band = np.arange(lw) % e  # band of each lane of a stream row
+    return packed.T[:, band].reshape(256, lw // LANES, LANES).transpose(
+        1, 0, 2
+    )
+
+
+def _block_diag_basis(n: int, e: int):
+    """bf16[3, Lw, (Lw/E) * N]: the iDCT basis slices repeated on the
+    diagonal, one window per ``E`` lanes of a stream row."""
+    b_sl, _ = dct.idct_basis_slices(n, e)
+    eye = np.eye(row_lanes(e) // e, dtype=np.float32)
+    return jnp.asarray(
+        np.stack([np.kron(eye, b) for b in b_sl]), jnp.bfloat16
+    )
 
 
 def _fused_kernel(
     hi_ref,
     lo_ref,
-    sl_ref,
-    dec_limit_ref,
-    dec_first_ref,
-    dec_rank_ref,
-    dec_syms_ref,
-    lut_ref,  # f32[E, 256] — quant_grid reconstruction values
-    basis_ref,  # f32[E, N]
+    sl_ref,  # SMEM int32[BW]
+    limit_ref,
+    first_ref,
+    rank_ref,
+    syms_ref,
+    lutp_ref,  # int32[T, 256, 128] split LUT per row phase
+    bd_ref,  # bf16[3, Lw, (Lw/E) * N] block-diagonal basis slices
+    factor_ref,  # SMEM f32[1]: basis scale x LUT split scale
     # remaining refs: [idx_ref, seg_ref] (v3 coding only), then
-    #   out_ref   f32[BLOCK_WINDOWS, N]
-    #   syms_ref  VMEM scratch int32[cap]: the dense symbol stream
-    #   tile_ref  VMEM scratch int32[max_symlen, BLOCK_WORDS]
+    #   out_ref   f32[rows_per_block, (Lw/E) * N]
+    #   dense_ref VMEM scratch int32[rows, 128]: the dense symbol stream
+    #   tile_ref  VMEM scratch int32[128, BLOCK_WORDS]
+    #   tilet_ref VMEM scratch int32[BLOCK_WORDS, 128]
     #   base_ref  SMEM scratch int32[1]
     *refs,
     l_max: int,
     max_symlen: int,
     num_word_blocks: int,
-    block_windows: int,
+    block_rows: int,
     e: int,
     coding=_TRIVIAL,
 ):
     if coding == _TRIVIAL:
-        out_ref, syms_ref, tile_ref, base_ref = refs
+        out_ref, dense_ref, tile_ref, tilet_ref, base_ref = refs
     else:
-        idx_ref, seg_ref, out_ref, syms_ref, tile_ref, base_ref = refs
+        (idx_ref, seg_ref, out_ref, dense_ref, tile_ref, tilet_ref,
+         base_ref) = refs
     i = pl.program_id(0)
+    phases = lutp_ref.shape[0]  # Lw / 128
 
     @pl.when(i == 0)
     def _init():
         base_ref[0] = 0
-        syms_ref[...] = jnp.zeros(syms_ref.shape, syms_ref.dtype)
+        dense_ref[...] = jnp.zeros(dense_ref.shape, dense_ref.dtype)
+        tile_ref[...] = jnp.zeros(tile_ref.shape, tile_ref.dtype)
 
     @pl.when(i < num_word_blocks)
     def _decode_phase():
         base = base_ref[0]
         decoded = decode_block_to_dense(
-            hi_ref[...],
-            lo_ref[...],
-            sl_ref[...],
-            dec_limit_ref[...],
-            dec_first_ref[...],
-            dec_rank_ref[...],
-            dec_syms_ref[...].astype(jnp.float32),
-            syms_ref,
-            tile_ref,
-            base,
-            l_max=l_max,
-            max_symlen=max_symlen,
+            hi_ref[...], lo_ref[...], sl_ref, limit_ref, first_ref,
+            rank_ref, syms_ref[...], dense_ref, tile_ref, tilet_ref, base,
+            l_max=l_max, max_symlen=max_symlen,
         )
         base_ref[0] = base + decoded
 
@@ -140,34 +183,55 @@ def _fused_kernel(
         # per window segment — the SAME reference inverse the host decoder
         # and the XLA bucket arm call, so all three stay bit-identical.
         # Runs exactly once, on the first window-phase step, before any
-        # window block reads the scratch back.
+        # window block reads the scratch back.  Gathers and a cumsum:
+        # interpret mode only (ops.py refuses v3 codings on the chip).
         @pl.when(i == num_word_blocks)
         def _recode_phase():
-            dense = syms_ref[...]  # materialized value (no aliasing with
-            # the write below)
+            dense = dense_ref[...].reshape(-1)
             grid = expand_coded_stream(dense, idx_ref[...])
             grid = grid.reshape(-1, e)  # [nwp, e]
             lvl = unpredict_levels(
                 grid.astype(jnp.uint32), seg_ref[...], pred_id, bands
             ).astype(jnp.int32)
             flat = lvl.reshape(-1)
-            spill = syms_ref.shape[0] - flat.shape[0]
-            if spill:
-                flat = jnp.concatenate(
-                    [flat, jnp.full((spill,), 128, jnp.int32)]
-                )
-            syms_ref[...] = flat
+            spill = dense.shape[0] - flat.shape[0]
+            flat = jnp.concatenate([flat, jnp.full((spill,), 128, jnp.int32)])
+            dense_ref[...] = flat.reshape(dense_ref.shape)
 
     @pl.when(i >= num_word_blocks)
     def _idct_phase():
-        j = i - num_word_blocks
-        levels = pl.load(
-            syms_ref, (pl.dslice(j * block_windows * e, block_windows * e),)
-        ).reshape(block_windows, e)
-        coeffs = lut_dequant(levels, lut_ref[...])
-        out_ref[...] = jnp.dot(
-            coeffs, basis_ref[...], preferred_element_type=jnp.float32
-        )
+        row0 = pl.multiple_of((i - num_word_blocks) * block_rows * phases, 8)
+        prods = {}
+        for t in range(phases):
+            rows = (
+                pl.ds(row0, block_rows) if phases == 1
+                else pl.ds(row0 + t, block_rows, phases)
+            )
+            levels = dense_ref[rows, :]
+
+            def select(v, acc, t=t, levels=levels):
+                return jnp.where(
+                    levels == v, lutp_ref[t, pl.ds(v, 1), :], acc
+                )
+
+            packed = jax.lax.fori_loop(
+                0, 256, select, jnp.zeros(levels.shape, jnp.int32)
+            )
+            c = [
+                (
+                    ((packed >> (_SLICE_FIELD * s)) & 1023) - 256
+                ).astype(jnp.float32).astype(jnp.bfloat16)
+                for s in range(3)
+            ]
+            for group in dct.SLICE_PAIRS:
+                for a, b in group:
+                    p = jnp.dot(
+                        c[a], bd_ref[b, pl.ds(t * LANES, LANES), :],
+                        preferred_element_type=jnp.float32,
+                    )
+                    prods[a, b] = p if t == 0 else prods[a, b] + p
+        acc = dct.combine_slice_products(lambda a, b: prods[a, b])
+        out_ref[...] = acc * factor_ref[0]
 
 
 @functools.partial(
@@ -181,6 +245,7 @@ def _fused_kernel(
         "coding",
         "block_words",
         "block_windows",
+        "vmem_limit_bytes",
         "interpret",
     ),
 )
@@ -193,7 +258,7 @@ def decode_fused(
     dec_rank: jnp.ndarray,
     dec_syms: jnp.ndarray,
     lut: jnp.ndarray,  # f32[E, 256] quant_grid LUT
-    basis: jnp.ndarray,  # f32[E, N] idct basis
+    rscale: jnp.ndarray,  # f32[] recon_scale the split is taken against
     idx: jnp.ndarray = None,  # int32[num_windows * e] (v3 coding only)
     seg: jnp.ndarray = None,  # int32[num_windows] (v3 coding only)
     *,
@@ -205,7 +270,8 @@ def decode_fused(
     coding=_TRIVIAL,
     block_words: int = BLOCK_WORDS,
     block_windows: int = BLOCK_WINDOWS,
-    interpret: bool = True,
+    vmem_limit_bytes: int = None,
+    interpret: bool,
 ) -> jnp.ndarray:
     """One ``pallas_call``: packed bucket words -> windows f32[num_windows, N].
 
@@ -216,69 +282,67 @@ def decode_fused(
     scratch + re-zeroed spill, matching the XLA scatter's zero fill), so
     even padding windows come out bit-identical to the XLA bucket arm.
 
-    A non-trivial ``coding`` (container v3) keeps the single-dispatch shape:
-    the coded-stream expansion + un-prediction epilogue
-    (``quantize.expand_coded_stream`` / ``unpredict_levels``) runs in-kernel
-    on the first window-phase grid step, rewriting the dense scratch from
-    coded symbols to plain levels before any window block dequantizes.
-    ``idx``/``seg`` are the host-built expansion arrays
-    (``symlen.v3_expand_index``); they are padded here to the kernel's
-    window-block rounding (-1 / self-segments, which expand and un-predict
-    to the zero bin 128 — exactly the XLA arm's padding semantics).
+    ``block_windows`` rounds up to :func:`window_align` (whole stream rows
+    per block).  A non-trivial ``coding`` (container v3) keeps the
+    single-dispatch shape: the coded-stream expansion + un-prediction
+    epilogue (``quantize.expand_coded_stream`` / ``unpredict_levels``) runs
+    in-kernel on the first window-phase grid step.  ``idx``/``seg`` are the
+    host-built expansion arrays (``symlen.v3_expand_index``); they are
+    padded here to the kernel's window-block rounding (-1 / self-segments,
+    which expand and un-predict to the zero bin 128 — exactly the XLA arm's
+    padding semantics).
     """
     coding = tuple(coding)
-    w = hi.shape[0]
-    block_words = min(block_words, max(w, 1))
-    num_word_blocks = -(-w // block_words)
-    wp = num_word_blocks * block_words
-    if wp != w:
-        hi = jnp.pad(hi, (0, wp - w))
-        lo = jnp.pad(lo, (0, wp - w))
-        symlen = jnp.pad(symlen, (0, wp - w))
-    block_windows = min(block_windows, max(num_windows, 1))
+    block_words = max(min(word_block(block_words, interpret), hi.shape[0]), 1)
+    nwb, hi, lo, symlen = word_operands(hi, lo, symlen, block_words)
+    align = window_align(e)
+    block_windows = -(-min(block_windows, max(num_windows, 1)) // align) * align
     num_win_blocks = -(-num_windows // block_windows)
     nwp = num_win_blocks * block_windows
-
-    # dense symbol scratch: every window slot plus one tile row of spill
-    cap = -(-(nwp * e + max_symlen) // 128) * 128
-    nwb = num_word_blocks
+    lw = row_lanes(e)
+    block_rows = block_windows * e // lw
+    rows = dense_rows(nwp * e, max_symlen)
     kernel = functools.partial(
         _fused_kernel,
         l_max=l_max,
         max_symlen=max_symlen,
         num_word_blocks=nwb,
-        block_windows=block_windows,
+        block_rows=block_rows,
         e=e,
         coding=coding,
     )
+    _, b_scale = dct.idct_basis_slices(n, e)
+    s, _ = dct.pow2_above(jnp.asarray(rscale, jnp.float32))
+    factor = jnp.reshape(s * b_scale, (1,))
+    lutp = _split_lut(lut, rscale, e)
+    bd = _block_diag_basis(n, e)
 
     def word_ix(i):
-        return (jnp.minimum(i, nwb - 1),)
+        return (0, jnp.minimum(i, nwb - 1))
 
-    def rep(i):
-        return (0,)
+    def whole(shape):
+        return pl.BlockSpec(
+            shape, lambda i: (0,) * len(shape),
+            pipeline_mode=pl.Buffered(1),
+        )
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((block_words,), word_ix),
-        pl.BlockSpec((block_words,), word_ix),
-        pl.BlockSpec((block_words,), word_ix),
-        pl.BlockSpec((dec_limit.shape[0],), rep),
-        pl.BlockSpec((dec_first.shape[0],), rep),
-        pl.BlockSpec((dec_rank.shape[0],), rep),
-        pl.BlockSpec((256,), rep),
-        pl.BlockSpec((e, 256), lambda i: (0, 0)),
-        pl.BlockSpec((e, n), lambda i: (0, 0)),
+        pl.BlockSpec((1, block_words), word_ix),
+        pl.BlockSpec((1, block_words), word_ix),
+        pl.BlockSpec(
+            (block_words,), lambda i: (jnp.minimum(i, nwb - 1),),
+            memory_space=pltpu.SMEM,
+        ),
+        *table_specs(),
+        whole(lutp.shape),
+        whole(bd.shape),
+        smem,
     ]
     operands = [
-        hi,
-        lo,
-        symlen.astype(jnp.int32),
-        dec_limit,
-        dec_first,
-        dec_rank,
-        dec_syms,
-        lut,
-        basis,
+        hi, lo, symlen,
+        *table_operands(dec_limit, dec_first, dec_rank, dec_syms),
+        lutp, bd, factor,
     ]
     if coding != _TRIVIAL:
         if idx is None or seg is None:
@@ -296,26 +360,30 @@ def decode_fused(
             seg = jnp.concatenate(
                 [seg, jnp.arange(seg.shape[0], nwp, dtype=jnp.int32)]
             )
-        in_specs += [
-            pl.BlockSpec((nwp * e,), rep),
-            pl.BlockSpec((nwp,), rep),
-        ]
+        in_specs += [whole((nwp * e,)), whole((nwp,))]
         operands += [idx, seg]
 
+    width = lw // e * n
     out = pl.pallas_call(
         kernel,
         grid=(nwb + num_win_blocks,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (block_windows, n),
-            lambda i: (jnp.maximum(i - nwb, 0), 0),
+            (block_rows, width), lambda i: (jnp.maximum(i - nwb, 0), 0),
         ),
-        out_shape=jax.ShapeDtypeStruct((nwp, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (nwp * e // lw, width), jnp.float32
+        ),
         scratch_shapes=[
-            pltpu.VMEM((cap,), jnp.int32),
-            pltpu.VMEM((max_symlen, block_words), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((LANES, block_words), jnp.int32),
+            pltpu.VMEM((block_words, LANES), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes,
+        ),
         interpret=interpret,
     )(*operands)
-    return out[:num_windows]
+    return out.reshape(nwp, n)[:num_windows]

@@ -1,40 +1,41 @@
-"""Pallas TPU kernel: fused encode bucket — DCT + quantize + SymLen pack.
+"""Pallas TPU kernel: the encode bucket's entropy stage — codeword lookup +
+greedy SymLen pack + word materialization in one ``pallas_call``.
 
-``kernels/dct_quant.py`` hand-tiles the lossy half of the encoder; this
-kernel extends that tile all the way into Huffman codeword emission so a
-whole encode bucket is ONE ``pallas_call``: windows -> DCT (MXU) ->
-3-zone quantize -> per-symbol (length, code) lookup via the one-hot
-matmul idiom -> chunk-parallel SymLen word materialization, all in one
-VMEM residency.  The grid runs ``block_rows`` signals per step (1 by
-default; the autotuner sweeps it — rows are independent, so the knob
-trades VMEM footprint against per-step overhead without touching bytes);
-each row packs its chunks concurrently (the scan carries only the O(1)
-bit-offset/word-index recurrence, vectorized across the chunk axis).
+The serving engine's encode is DCT + quantize (+ the container-v3 coding
+stage) down to a symbol grid, then the chunk-parallel SymLen pack
+(``repro.core.symlen.pack_symlen_chunked_parts``).  The front half is
+elementwise work and two small matmuls that XLA already fuses well; the
+pack is where XLA pays: one ``while`` iteration per symbol slot, plus a
+cumsum, a searchsorted and three gathers per chunk.  This kernel runs the
+whole pack in VMEM:
 
-Bit parity is by construction, not by luck:
+  layout   the bucket's chunks side by side in lanes: ``syms[C, L]`` holds
+           slot ``j`` of chunk ``l`` at ``[j, l]`` (every signal row's
+           chunks, the rows concatenated; masked slots carry -1), so a
+           grid step packs ``block_lanes`` independent chunks at once;
+  lookup   (code, length) per slot by integer selection against the
+           256-entry tables in SMEM — no gather, no float cast;
+  pack     one sequential loop over the C slots carrying only the O(1)
+           (bit offset, word index) recurrence per lane — the recurrence
+           of ``symlen._pack_chunk_emit``;
+  words    each word is the sum of its symbols' shifted codes (the bits
+           are disjoint, so the int32 sum is the OR), one masked
+           sublane-reduction per word slot up to the block's widest chunk.
 
-  * the quantizer is ``repro.core.quantize.quantize`` itself (the exact
-    reference math, traced inside the kernel);
-  * the (code, length) lookup is a one-hot ``[C, 256]`` matmul whose f32
-    sums are exact (codewords are < 2^l_max <= 2^24, lengths <= 64);
-  * the word materialization calls ``repro.core.symlen._pack_chunk_emit``
-    — literally the same segment-sum code the XLA path runs — under an
-    in-kernel ``vmap`` over chunks.
+The output is exactly the XLA packer's chunk parts — first
+``words_per_chunk`` words per chunk, zeros after — so the two arms are
+byte-identical, and the stream is the same bits on every backend.
 
-So ``BatchEncoder(use_kernels=True)`` produces byte-identical streams to
-the XLA engine path (pinned by the golden + conformance suites in
-interpret mode).
-
-VMEM budget per grid step (Wp windows, N, E <= 128, chunk C, B chunks):
-  signal row                     4 B * Wp * N
-  coeffs / levels                4 B * Wp * E (x2)
-  one-hot lookup block           4 B * B * C * 256  (whole-signal; the
-                                 kernel's largest transient — 4 MiB at
-                                 Sp = 4096 symbols)
-  chunk parts out                4 B * 3 * B * C
-On real TPU the one-hot block wants per-chunk tiling (a ROADMAP
-follow-up); in interpret mode (how these kernels are validated) XLA fuses
-it and the block never materializes.
+VMEM per grid step (C slots, LB lanes, Wb = word slots per chunk):
+  syms in (double-buffered)          2 * 4 B * C * LB
+  code / length / index / offset     4 * 4 B * C * LB  (scratch)
+  the body's [C, LB] values          ~6 * 4 B * C * LB (masks, shifts, ...)
+  hi / lo / symlen out (dbl-buf)     6 * 4 B * Wb * LB
+At C = 1024, LB = 128 that is ~7 MiB.  Compiled for a TPU v5e (128 MiB of
+VMEM, a 96 MiB scoped limit), C = 12288 fits and C = 16384 does not;
+``repro.kernels.ops`` refuses a bucket whose step would not fit
+(:class:`~repro.kernels.ops.KernelLimitError`) — exact-mode encodes (one
+chunk per signal) reach that at about 12k symbols per signal.
 """
 from __future__ import annotations
 
@@ -43,279 +44,157 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantize import QuantTable, predict_levels, quantize
-from repro.core.symlen import _pack_chunk_emit
+__all__ = ["encode_pack", "BLOCK_LANES", "step_vmem_bytes"]
 
-__all__ = ["encode_fused"]
+BLOCK_LANES = 128
+WORD_BITS = 64
 
-_TRIVIAL = (0, 0, False)  # no predictor, no zero planes: the v2 stream
+
+def _shl(x, s):
+    """int32 left shift, 0 for s >= 32 or s < 0 (``symlen._shl32``)."""
+    v = x << jnp.clip(s, 0, 31)
+    return jnp.where((s >= 32) | (s < 0), 0, v)
+
+
+def _shr(x, s):
+    """int32 logical right shift, 0 for s >= 32 or s < 0."""
+    v = jax.lax.shift_right_logical(x, jnp.clip(s, 0, 31))
+    return jnp.where((s >= 32) | (s < 0), 0, v)
 
 
 def _kernel(
-    sig_ref,  # f32[R, Wp * N] — R = block_rows signals per grid step
-    counts_ref,  # int32[R] — true symbol count per signal
-    codes_ref,  # uint32[256]
-    lengths_ref,  # int32[256]
-    zone_ref,  # int32[E]
-    scale_ref,  # f32[E]
-    mu_ref,  # f32[1]
-    alpha1_ref,  # f32[1]
-    basis_ref,  # f32[N, E] (dct_basis)
-    # out refs: hi, lo, sl, wpc, bad [+ ncoded [+ zrow, zcol] under a
-    # non-trivial coding] — arity is fixed at trace time by ``coding``
-    *out_refs,
-    n: int,
-    e: int,
-    num_chunks: int,
-    chunk_size: int,
-    check_gaps: bool,
-    coding=_TRIVIAL,
+    codes_ref,  # SMEM int32[256] right-aligned codewords
+    lengths_ref,  # SMEM int32[256]
+    syms_ref,  # int32[C, LB] symbol per slot, -1 = masked slot
+    hi_ref,  # out int32[Wb, LB]
+    lo_ref,  # out int32[Wb, LB]
+    sl_ref,  # out int32[Wb, LB]
+    wpc_ref,  # out int32[1, LB]
+    code_s,  # VMEM scratch int32[C, LB]: code, then hi contribution
+    len_s,  # VMEM scratch int32[C, LB]: length, then lo contribution
+    widx_s,  # VMEM scratch int32[C, LB]: word index per slot
+    start_s,  # VMEM scratch int32[C, LB]: bit offset per slot
 ):
-    pred_id, bands, zplanes = coding
-    if coding == _TRIVIAL:
-        hi_ref, lo_ref, sl_ref, wpc_ref, bad_ref = out_refs
-        nc_ref = zr_ref = zc_ref = None
-    elif zplanes:
-        (hi_ref, lo_ref, sl_ref, wpc_ref, bad_ref,
-         nc_ref, zr_ref, zc_ref) = out_refs
-    else:
-        hi_ref, lo_ref, sl_ref, wpc_ref, bad_ref, nc_ref = out_refs
-        zr_ref = zc_ref = None
-    quant = QuantTable(
-        zone=zone_ref[...],
-        scale=scale_ref[...],
-        mu=mu_ref[0],
-        alpha1=alpha1_ref[0],
+    syms = syms_ref[...]
+
+    def lookup(v, carry):
+        code, clen = carry
+        hit = syms == v
+        return (
+            jnp.where(hit, codes_ref[v], code),
+            jnp.where(hit, lengths_ref[v], clen),
+        )
+
+    zero = jnp.zeros(syms.shape, jnp.int32)
+    code, clen = jax.lax.fori_loop(0, 256, lookup, (zero, zero))
+    code_s[...] = code
+    len_s[...] = clen
+
+    def slot(j, carry):
+        bit, w = carry
+        cl = len_s[pl.ds(j, 1), :]
+        flush = bit + cl > WORD_BITS
+        w = w + flush.astype(jnp.int32)
+        start = jnp.where(flush, 0, bit)
+        widx_s[pl.ds(j, 1), :] = w
+        start_s[pl.ds(j, 1), :] = start
+        return start + cl, w
+
+    row0 = jnp.zeros((1, syms.shape[1]), jnp.int32)
+    jax.lax.fori_loop(0, syms.shape[0], slot, (row0, row0))
+
+    valid = syms >= 0
+    widx = widx_s[...]
+    # right-aligned `code` of length clen lands at bit offset `start`
+    # (MSB-first): hi takes the bits when shift >= 32
+    shift = WORD_BITS - start_s[...] - clen
+    code_s[...] = jnp.where(
+        shift >= 32, _shl(code, shift - 32), _shr(code, 32 - shift)
     )
-    basis = basis_ref[...]
-    codes_f = codes_ref[...].astype(jnp.float32)  # exact: < 2^l_max <= 2^24
-    lengths_f = lengths_ref[...].astype(jnp.float32)
-    sym_iota = jnp.arange(256, dtype=jnp.int32)
-    cap = num_chunks * chunk_size
+    len_s[...] = jnp.where(shift >= 32, 0, _shl(code, shift))
+    wpc = jnp.max(jnp.where(valid, widx + 1, 0), axis=0, keepdims=True)
+    wpc_ref[...] = wpc
 
-    def one_row(sig, count):
-        windows = sig.reshape(-1, n)  # [Wp, N]
-        coeffs = jnp.dot(
-            windows, basis, preferred_element_type=jnp.float32
-        )  # [Wp, E]
-        # the exact reference quantizer — same ops the XLA path traces, so
-        # the levels (hence every packed bit) are identical under jit
-        levels = quantize(coeffs, quant)  # uint8[Wp, E]
-        if coding == _TRIVIAL:
-            syms = levels.reshape(-1).astype(jnp.int32)  # [Sp]
-            if cap != syms.shape[0]:
-                syms = jnp.pad(syms, (0, cap - syms.shape[0]))
-            valid = jnp.arange(cap, dtype=jnp.int32) < count
-            extras = ()
-        else:
-            # the v3 prologue — the SAME reference transform the XLA engine
-            # arm traces (quantize.predict_levels + the zero-plane masks),
-            # fused between quantization and the codeword lookup
-            grid = predict_levels(levels, pred_id, bands)  # uint8[Wp, E]
-            w = grid.shape[0]
-            win_valid = (
-                jnp.arange(w, dtype=jnp.int32) < count // e
-            )  # true (non-padding) windows of this row
-            if zplanes:
-                is_zero = grid == jnp.uint8(128)
-                zrow = jnp.all(is_zero, axis=1)  # [Wp]
-                zcol = jnp.all(
-                    is_zero | ~win_valid[:, None], axis=0
-                )  # [E], over true windows only
-                valid2 = (win_valid & ~zrow)[:, None] & ~zcol[None, :]
-            else:
-                valid2 = jnp.broadcast_to(win_valid[:, None], grid.shape)
-            syms = grid.reshape(-1).astype(jnp.int32)
-            valid = valid2.reshape(-1)
-            if cap != syms.shape[0]:
-                syms = jnp.pad(syms, (0, cap - syms.shape[0]))
-                valid = jnp.pad(valid, (0, cap - valid.shape[0]))
-            if zplanes:
-                ncoded = jnp.sum(valid, dtype=jnp.int32)
-                extras = (
-                    ncoded, zrow.astype(jnp.int32), zcol.astype(jnp.int32)
-                )
-            else:
-                extras = (count,)
+    hi_ref[...] = jnp.zeros(hi_ref.shape, jnp.int32)
+    lo_ref[...] = jnp.zeros(lo_ref.shape, jnp.int32)
+    sl_ref[...] = jnp.zeros(sl_ref.shape, jnp.int32)
 
-        # one batched one-hot lookup for the whole signal (a single MXU
-        # matmul equation — an unrolled per-chunk loop traces O(B) ops for
-        # the same exact integer selections); the [cap, 256] block is the
-        # kernel's largest transient, see the module docstring's VMEM note
-        onehot = (syms[:, None] == sym_iota[None, :]).astype(jnp.float32)
-        raw_code = (
-            jnp.dot(onehot, codes_f, preferred_element_type=jnp.float32)
-            .astype(jnp.uint32).reshape(num_chunks, chunk_size)
+    def word(k, _):
+        hit = widx_s[...] == k
+        hi_ref[pl.ds(k, 1), :] = jnp.sum(
+            jnp.where(hit, code_s[...], 0), axis=0, keepdims=True
         )
-        raw_len = (
-            jnp.dot(onehot, lengths_f, preferred_element_type=jnp.float32)
-            .astype(jnp.int32).reshape(num_chunks, chunk_size)
+        lo_ref[pl.ds(k, 1), :] = jnp.sum(
+            jnp.where(hit, len_s[...], 0), axis=0, keepdims=True
         )
-        validr = valid.reshape(num_chunks, chunk_size)
-        if check_gaps:
-            bad = jnp.any((raw_len == 0) & validr).astype(jnp.int32)
-        else:
-            bad = jnp.zeros((), jnp.int32)
-        # masked slots emit a zero-length, zero-valued code: a no-op (the
-        # same masking _pack_chunk applies before its emit)
-        code = jnp.where(validr, raw_code, jnp.uint32(0))
-        clen = jnp.where(validr, raw_len, 0)
-        hi, lo, sl, wpc = jax.vmap(_pack_chunk_emit)(code, clen, validr)
-        return (hi, lo, sl, wpc, bad) + extras
+        sl_ref[pl.ds(k, 1), :] = jnp.sum(
+            (hit & (syms_ref[...] >= 0)).astype(jnp.int32), axis=0,
+            keepdims=True,
+        )
+        return 0
 
-    # rows are independent signals: vmap keeps every per-row selection /
-    # pack identical to the one-row kernel while a tuned block_rows > 1
-    # amortizes the per-step dispatch overhead across R rows
-    outs = jax.vmap(one_row)(sig_ref[...], counts_ref[...])
-    hi_ref[...] = outs[0]
-    lo_ref[...] = outs[1]
-    sl_ref[...] = outs[2]
-    wpc_ref[...] = outs[3]
-    bad_ref[...] = outs[4]
-    if nc_ref is not None:
-        nc_ref[...] = outs[5]
-    if zr_ref is not None:
-        zr_ref[...] = outs[6]
-        zc_ref[...] = outs[7]
+    # a chunk with no valid slot still owns word 0 (zero bits, symlen 0),
+    # exactly like the XLA packer's segment sums
+    jax.lax.fori_loop(0, jnp.max(wpc), word, 0)
+
+
+def step_vmem_bytes(chunk_size: int, word_slots: int, lanes: int) -> int:
+    """VMEM one grid step holds (see the module docstring)."""
+    return 4 * lanes * (12 * chunk_size + 6 * word_slots + 2)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "n", "e", "chunk_size", "check_gaps", "coding", "block_rows",
-        "interpret",
+        "word_slots", "block_lanes", "vmem_limit_bytes", "interpret"
     ),
 )
-def encode_fused(
-    signals: jnp.ndarray,  # f32[K, Wp * N] (zero-padded signal rows)
-    counts: jnp.ndarray,  # int32[K] true symbol count per signal
+def encode_pack(
+    syms: jnp.ndarray,  # int32[C, L] slot-major chunks, -1 = masked slot
     codes: jnp.ndarray,  # uint32[256]
     lengths: jnp.ndarray,  # int32[256]
-    zone: jnp.ndarray,  # int32[E]
-    scale: jnp.ndarray,  # f32[E]
-    mu: jnp.ndarray,
-    alpha1: jnp.ndarray,
-    basis: jnp.ndarray,  # f32[N, E] dct_basis
     *,
-    n: int,
-    e: int,
-    chunk_size: int,
-    check_gaps: bool,
-    coding=_TRIVIAL,
-    block_rows: int = 1,
-    interpret: bool = True,
+    word_slots: int,
+    block_lanes: int,
+    vmem_limit_bytes: int = None,
+    interpret: bool,
 ):
-    """Fused bucket encode, one ``pallas_call``: signal rows -> chunk parts.
-
-    Returns ``(hi uint32[K, B, C], lo uint32[K, B, C], symlen int32[K, B,
-    C], words_per_chunk int32[K, B], bad bool[K])`` — exactly the contract
-    of the XLA path (``vmap`` of :func:`repro.core.symlen.
-    pack_symlen_chunked_parts` plus the batch-wide histogram-gap flag),
-    byte for byte.  A non-trivial ``coding`` (container v3) appends the
-    XLA arm's extra outputs: per-signal coded-symbol counts ``ncoded
-    int32[K]`` and — with zero planes — ``zrow bool[K, Wp]`` / ``zcol
-    bool[K, E]``; the v3 prologue (prediction + zero-plane masking) runs
-    inside the same single ``pallas_call``.
-
-    ``block_rows`` is the autotuner's knob: signals packed per grid step
-    (rows are independent, so it trades per-step VMEM footprint against
-    per-step dispatch overhead and NEVER changes bytes — the batch pads up
-    to a row multiple with zero-count rows, which pack zero words, and the
-    outputs slice back to ``K``).
-    """
-    coding = tuple(coding)
-    zplanes = coding != _TRIVIAL and bool(coding[2])
-    k, width = signals.shape
-    wp = width // n
-    sp = wp * e
-    num_chunks = max(-(-sp // chunk_size), 1)
-    br = max(min(int(block_rows), max(k, 1)), 1)
-    kp = -(-k // br) * br
-    if kp != k:
-        signals = jnp.pad(signals, ((0, kp - k), (0, 0)))
-        counts = jnp.pad(counts, (0, kp - k))
-    kernel = functools.partial(
+    """Pack ``L`` chunks of ``C`` slots: returns ``(hi uint32[Wb, L], lo
+    uint32[Wb, L], symlen int32[Wb, L], words_per_chunk int32[L])`` with
+    ``Wb = word_slots`` (a static bound on any chunk's word count, e.g.
+    :func:`repro.core.symlen.chunk_words_bound`).  Lanes are padded to a
+    ``block_lanes`` multiple with all-masked chunks and sliced back."""
+    c, l = syms.shape
+    lb = l if l <= block_lanes else block_lanes
+    lp = -(-l // lb) * lb
+    if lp != l:
+        syms = jnp.pad(syms, ((0, 0), (0, lp - l)), constant_values=-1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    lanes = pl.BlockSpec((c, lb), lambda i: (0, i))
+    words = pl.BlockSpec((word_slots, lb), lambda i: (0, i))
+    hi, lo, sl, wpc = pl.pallas_call(
         _kernel,
-        n=n,
-        e=e,
-        num_chunks=num_chunks,
-        chunk_size=chunk_size,
-        check_gaps=check_gaps,
-        coding=coding,
-    )
-
-    def row(i):
-        return (i, 0)
-
-    def row3(i):
-        return (i, 0, 0)
-
-    def rep(i):
-        return (0,)
-
-    out_specs = [
-        pl.BlockSpec((br, num_chunks, chunk_size), row3),
-        pl.BlockSpec((br, num_chunks, chunk_size), row3),
-        pl.BlockSpec((br, num_chunks, chunk_size), row3),
-        pl.BlockSpec((br, num_chunks), row),
-        pl.BlockSpec((br,), lambda i: (i,)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((kp, num_chunks, chunk_size), jnp.uint32),
-        jax.ShapeDtypeStruct((kp, num_chunks, chunk_size), jnp.uint32),
-        jax.ShapeDtypeStruct((kp, num_chunks, chunk_size), jnp.int32),
-        jax.ShapeDtypeStruct((kp, num_chunks), jnp.int32),
-        jax.ShapeDtypeStruct((kp,), jnp.int32),
-    ]
-    if coding != _TRIVIAL:
-        out_specs.append(pl.BlockSpec((br,), lambda i: (i,)))
-        out_shape.append(jax.ShapeDtypeStruct((kp,), jnp.int32))
-    if zplanes:
-        out_specs += [
-            pl.BlockSpec((br, wp), row),
-            pl.BlockSpec((br, e), row),
-        ]
-        out_shape += [
-            jax.ShapeDtypeStruct((kp, wp), jnp.int32),
-            jax.ShapeDtypeStruct((kp, e), jnp.int32),
-        ]
-    outs = pl.pallas_call(
-        kernel,
-        grid=(kp // br,),
-        in_specs=[
-            pl.BlockSpec((br, width), row),
-            pl.BlockSpec((br,), lambda i: (i,)),
-            pl.BlockSpec((256,), rep),
-            pl.BlockSpec((256,), rep),
-            pl.BlockSpec((e,), rep),
-            pl.BlockSpec((e,), rep),
-            pl.BlockSpec((1,), rep),
-            pl.BlockSpec((1,), rep),
-            pl.BlockSpec((n, e), lambda i: (0, 0)),
+        grid=(lp // lb,),
+        in_specs=[smem, smem, lanes],
+        out_specs=[words, words, words, pl.BlockSpec((1, lb), lambda i: (0, i))],
+        out_shape=[
+            jax.ShapeDtypeStruct((word_slots, lp), jnp.int32),
+            jax.ShapeDtypeStruct((word_slots, lp), jnp.int32),
+            jax.ShapeDtypeStruct((word_slots, lp), jnp.int32),
+            jax.ShapeDtypeStruct((1, lp), jnp.int32),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((c, lb), jnp.int32)] * 4,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes,
+        ),
         interpret=interpret,
     )(
-        signals,
-        counts.astype(jnp.int32),
-        codes,
-        lengths,
-        zone,
-        scale,
-        jnp.reshape(mu.astype(jnp.float32), (1,)),
-        jnp.reshape(alpha1.astype(jnp.float32), (1,)),
-        basis,
+        jax.lax.bitcast_convert_type(codes.astype(jnp.uint32), jnp.int32),
+        lengths.astype(jnp.int32),
+        syms,
     )
-    outs = [o[:k] for o in outs] if kp != k else list(outs)
-    hi, lo, sl, wpc, bad = outs[:5]
-    if coding == _TRIVIAL:
-        return hi, lo, sl, wpc, bad > 0
-    ncoded = outs[5]
-    if zplanes:
-        zrow = outs[6].astype(jnp.bool_)
-        zcol = outs[7].astype(jnp.bool_)
-    else:
-        zrow = zcol = None
-    return hi, lo, sl, wpc, bad > 0, ncoded, zrow, zcol
+    u32 = functools.partial(jax.lax.bitcast_convert_type, new_dtype=jnp.uint32)
+    return u32(hi[:, :l]), u32(lo[:, :l]), sl[:, :l], wpc[0, :l]

@@ -1,28 +1,39 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These are the entry points the codec (``repro.core.codec`` with
-``use_kernels=True``) and the serving/benchmark layers call.  On CPU they run
-the kernels in interpret mode; on TPU set ``interpret=False`` (the default
-flips automatically on TPU backends).
+``use_kernels=True``) and the serving/benchmark layers call.  This module
+alone decides whether a kernel runs compiled by Mosaic (on a TPU backend)
+or in Pallas interpret mode (everywhere else, which is how the CPU test
+suite runs them); the raw kernels take ``interpret`` as a required
+argument.
 
-Since the megakernel PR the kernel surface is:
+The kernel surface:
 
   * :func:`huffman_decode` — ONE dispatch: the fused dense kernel decodes
     and compacts in the same ``pallas_call`` (the symlen sidecar rides into
     the kernel; no ``[max_symlen, W]`` HBM tile).
   * :func:`decode_bucket_fused` — the full decode megakernel: Huffman +
     compaction + LUT dequant + iDCT in a single ``pallas_call``.
-  * :func:`encode_bucket_fused` — the encode-side twin: DCT + quantize +
-    one-hot codeword lookup + chunk-parallel SymLen pack in one
-    ``pallas_call``, bit-identical to the XLA engine path.
+  * :func:`encode_bucket_fused` — the encode entropy stage: codeword lookup
+    + greedy SymLen pack + word materialization in one ``pallas_call``,
+    byte-identical to the XLA chunk packer.
   * :func:`idct_dequant` / :func:`dct_quant` — the staged per-stage tiles
-    (kept as oracles and for the legacy per-container baseline).
+    (the fixed-rate kernel arm, the legacy per-container baseline, and
+    oracles).
 
-Every wrapper guards the int32 offset range before dispatch: symbol/word
-offsets inside the kernels are int32 (jax default x32), so a bucket whose
-dense symbol stream would cross the 2^31-byte mark must raise loudly
-instead of wrapping offsets negative and compacting the wrong positions
-silently (the same guard discipline as the transcoder's flat-gather path).
+Every wrapper guards what the kernels cannot take before dispatch, with a
+typed error and never a silent switch to another path:
+
+  * int32 offsets (:func:`check_i32_offsets`): symbol/word offsets inside
+    the kernels are int32, so a bucket whose dense symbol stream would
+    cross the 2^31 mark must raise instead of wrapping offsets negative;
+  * VMEM (:class:`KernelLimitError`): the decode megakernel keeps the
+    bucket's whole dense symbol stream in VMEM scratch and the pack kernel
+    a ``[chunk, lanes]`` working set, so buckets past the recorded limits
+    are refused (decode in smaller batches, or page the scratch — future
+    work);
+  * the container-v3 decode epilogue (gathers + a prefix sum) has no Mosaic
+    lowering, so v3 buckets are refused on the chip.
 
 The megakernel wrappers resolve their Pallas block sizes at TRACE time:
 ``block_*=None`` (the engines' calling convention) consults the
@@ -33,8 +44,6 @@ on the tuning-cache epoch so a new entry forces a retrace.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,6 +51,7 @@ import numpy as np
 from repro.core import dct as _dct
 from repro.core.calibration import DeviceTables
 from repro.core.quantize import QuantTable
+from repro.core.symlen import chunk_words_bound
 from repro.kernels import dct_quant as _dq
 from repro.kernels import decode_fused as _df
 from repro.kernels import encode_fused as _ef
@@ -56,11 +66,32 @@ __all__ = [
     "idct_dequant",
     "dct_quant",
     "check_i32_offsets",
+    "check_decode_vmem",
+    "check_encode_vmem",
+    "decode_vmem_bytes",
+    "KernelLimitError",
+    "VMEM_BUDGET_BYTES",
+    "VMEM_LIMIT_BYTES",
     "on_tpu",
 ]
 
 _I32_MAX = np.iinfo(np.int32).max
 _TRIVIAL_CODING = (0, 0, False)
+
+# The VMEM the kernels ask Mosaic for.  A TPU v5e core has 128 MiB of VMEM
+# and a 16 MiB default scoped limit; the megakernels raise the limit to
+# this.  The wrappers refuse a bucket whose counted working set exceeds
+# VMEM_BUDGET_BYTES: compiled for v5e, Mosaic's own stack took up to 3.2 MiB
+# beyond what the kernels' buffers count, so 8 MiB are kept for it.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES - 8 * 1024 * 1024
+
+
+class KernelLimitError(ValueError):
+    """A bucket the Pallas kernels cannot take: past the int32 offset
+    range, past the VMEM limit, or a container coding with no TPU
+    lowering.  Raised before dispatch; the engines never fall back to the
+    XLA path on their own."""
 
 
 def _coding_key(coding) -> tuple:
@@ -92,20 +123,61 @@ def check_i32_offsets(num_symbols: int, max_symlen: int) -> None:
     Mirrors the transcoder's flat-gather int32 guard.
     """
     if int(num_symbols) + int(max_symlen) > _I32_MAX:
-        raise ValueError(
+        raise KernelLimitError(
             f"decode bucket of {num_symbols} symbols (+{max_symlen} spill) "
             "exceeds the int32 offset range of the fused kernels — decode "
             "the archive in smaller batches"
         )
 
 
-def _check_encode_i32(width: int, e: int, n: int) -> None:
-    """Encode-side arm of the int32 guard: per-signal symbol capacity."""
-    sp = (int(width) // int(n)) * int(e)
-    if sp > _I32_MAX:
-        raise ValueError(
-            f"encode bucket rows of {sp} symbols exceed the int32 offset "
-            "range of the fused pack kernel — encode in smaller windows"
+def decode_vmem_bytes(
+    num_windows: int, *, n: int, e: int, max_symlen: int,
+    block_words: int = _hd.BLOCK_WORDS,
+    block_windows: int = _df.BLOCK_WINDOWS,
+) -> int:
+    """VMEM the decode megakernel needs for a bucket of ``num_windows``
+    (rounded as the kernel rounds): the dense symbol scratch, the word
+    tiles, the split LUT, the block-diagonal basis and the double-buffered
+    output block (see :mod:`repro.kernels.decode_fused`)."""
+    align = _df.window_align(e)
+    bwin = -(-min(block_windows, max(num_windows, 1)) // align) * align
+    nwp = -(-max(num_windows, 1) // bwin) * bwin
+    lw = _df.row_lanes(e)
+    rows = _hd.dense_rows(nwp * e, max_symlen)
+    width = lw // e * n
+    return (
+        4 * _hd.LANES * rows
+        + 2 * 4 * _hd.LANES * block_words
+        + 2 * 2 * 4 * block_words
+        + 4 * 256 * lw
+        + 3 * 2 * lw * width
+        + 2 * 4 * (bwin * e // lw) * width
+    )
+
+
+def check_decode_vmem(num_windows: int, **kw) -> None:
+    """Refuse a decode bucket whose megakernel working set exceeds
+    :data:`VMEM_BUDGET_BYTES` (the dense symbol stream is whole-bucket)."""
+    need = decode_vmem_bytes(num_windows, **kw)
+    if need > VMEM_BUDGET_BYTES:
+        raise KernelLimitError(
+            f"decode bucket of {num_windows} windows (E={kw['e']}) needs "
+            f"{need} B of VMEM for the megakernel's dense symbol scratch; "
+            f"the limit is VMEM_BUDGET_BYTES={VMEM_BUDGET_BYTES} — decode "
+            "the archive in smaller batches"
+        )
+
+
+def check_encode_vmem(chunk_size: int, word_slots: int, lanes: int) -> None:
+    """Refuse an encode bucket whose pack-kernel step exceeds the limit
+    (exact-mode encodes make the whole signal one chunk)."""
+    need = _ef.step_vmem_bytes(chunk_size, word_slots, lanes)
+    if need > VMEM_BUDGET_BYTES:
+        raise KernelLimitError(
+            f"encode chunk of {chunk_size} symbols x {lanes} lanes needs "
+            f"{need} B of VMEM in the pack kernel; the limit is "
+            f"VMEM_BUDGET_BYTES={VMEM_BUDGET_BYTES} — encode with a smaller "
+            "chunk_size"
         )
 
 
@@ -121,11 +193,11 @@ def huffman_decode(
 ) -> jnp.ndarray:
     """SymLen decode + compaction: packed words -> dense uint8[num_symbols].
 
-    ONE dispatch: the symlen sidecar rides into the kernel, a VMEM-resident
-    exclusive prefix-scan assigns per-word output offsets, and the
-    cooperative store compacts symbols inside the same ``pallas_call`` —
-    container boundaries are invisible (the prefix sums are segment sums),
-    so concatenated batch streams decode in this single dispatch with no
+    ONE dispatch: the symlen sidecar rides into the kernel, a running
+    offset over it assigns per-word output positions, and the cooperative
+    store compacts symbols inside the same ``pallas_call`` — container
+    boundaries are invisible (the offsets are segment sums), so
+    concatenated batch streams decode in this single dispatch with no
     ``[max_symlen, W]`` HBM tile.  ``core.symlen.compact_padded_scatter``
     (over the staged tile kernel) remains the interpret-mode oracle.
     """
@@ -152,7 +224,7 @@ def decode_bucket_fused(
     symlen: jnp.ndarray,
     tables: DeviceTables,
     lut: jnp.ndarray,  # f32[E, 256] quant_grid reconstruction LUT
-    basis: jnp.ndarray,  # f32[E, N] idct basis
+    rscale: jnp.ndarray,  # f32[] recon_scale of the quant table
     v3=None,  # (idx, seg) expansion arrays for non-trivial codings
     *,
     l_max: int,
@@ -171,7 +243,8 @@ def decode_bucket_fused(
     A non-trivial ``coding`` (container v3) adds the in-kernel expansion +
     un-prediction epilogue; ``v3`` must then carry the host-built
     ``(idx, seg)`` arrays from :func:`repro.core.symlen.v3_expand_index`.
-    Still exactly one ``pallas_call``.
+    Still exactly one ``pallas_call`` — in interpret mode; on the chip a v3
+    bucket raises :class:`KernelLimitError`.
 
     ``block_words``/``block_windows`` default to the tuning cache's winner
     for this (backend, plan key, bucket shape) — or the kernel's built-in
@@ -179,6 +252,13 @@ def decode_bucket_fused(
     sweep path) bypass the consult."""
     check_i32_offsets(num_windows * e, max_symlen)
     coding = tuple(coding)
+    interpret = _interp()
+    if coding != _TRIVIAL_CODING and not interpret:
+        raise KernelLimitError(
+            f"container-v3 coding {coding} has no TPU lowering in the decode "
+            "megakernel (its expansion epilogue gathers) — decode v3 "
+            "buckets with use_kernels=False"
+        )
     if block_words is None or block_windows is None:
         tuned = _tuned_blocks(
             "decode",
@@ -189,6 +269,11 @@ def decode_bucket_fused(
             block_words = tuned.get("block_words", _hd.BLOCK_WORDS)
         if block_windows is None:
             block_windows = tuned.get("block_windows", _df.BLOCK_WINDOWS)
+    if not interpret:
+        check_decode_vmem(
+            num_windows, n=n, e=e, max_symlen=max_symlen,
+            block_words=int(block_words), block_windows=int(block_windows),
+        )
     idx, seg = v3 if v3 is not None else (None, None)
     return _df.decode_fused(
         hi,
@@ -199,7 +284,7 @@ def decode_bucket_fused(
         tables.dec_rank,
         tables.dec_syms,
         lut,
-        basis,
+        rscale,
         idx,
         seg,
         l_max=l_max,
@@ -210,89 +295,71 @@ def decode_bucket_fused(
         coding=coding,
         block_words=int(block_words),
         block_windows=int(block_windows),
-        interpret=_interp(),
+        vmem_limit_bytes=None if interpret else VMEM_LIMIT_BYTES,
+        interpret=interpret,
     )
 
 
 def encode_bucket_fused(
-    signals: jnp.ndarray,  # f32[K, Wp * N]
-    counts: jnp.ndarray,  # int32[K]
+    syms: jnp.ndarray,  # int32[K, Sp] symbols per signal row
+    valid: jnp.ndarray,  # bool[K, Sp] slots that pack
     tables: DeviceTables,
-    basis: jnp.ndarray,  # f32[N, E] dct_basis
     *,
-    n: int,
-    e: int,
     chunk_size: int,
-    check_gaps: bool,
-    coding=_TRIVIAL_CODING,
-    block_rows: int = None,
+    l_max: int,
+    block_lanes: int = None,
 ):
-    """The encode megakernel: signal rows -> SymLen chunk parts in one
-    ``pallas_call``, bit-identical to the XLA engine path (see
+    """The encode entropy stage in one ``pallas_call``: a bucket's symbol
+    rows -> SymLen chunk parts ``(hi uint32[K, B, C], lo, symlen int32[K,
+    B, C], words_per_chunk int32[K, B])``, byte-identical to ``vmap`` of
+    :func:`repro.core.symlen.pack_symlen_chunked_parts` over the rows (see
     :mod:`repro.kernels.encode_fused`).
 
-    A non-trivial ``coding`` (container v3) turns on the in-kernel
-    prediction + zero-plane prologue; the return grows the per-row
-    ``ncoded``/``zrow``/``zcol`` outputs (see
-    :func:`repro.kernels.encode_fused.encode_fused`).
-
-    ``block_rows`` (signals per grid step) defaults to the tuning cache's
-    winner for this (backend, plan key, bucket shape), falling back to 1;
-    explicit values bypass the consult (the autotuner's sweep path)."""
-    _check_encode_i32(signals.shape[1], e, n)
-    coding = tuple(coding)
-    if block_rows is None:
+    ``block_lanes`` (chunks packed per grid step) defaults to the tuning
+    cache's winner for this (backend, plan key, bucket shape), falling back
+    to :data:`~repro.kernels.encode_fused.BLOCK_LANES`; explicit values
+    bypass the consult (the autotuner's sweep path)."""
+    k, sp = syms.shape
+    c = int(chunk_size)
+    b = max(-(-sp // c), 1)
+    words = chunk_words_bound(c, l_max)
+    if block_lanes is None:
         tuned = _tuned_blocks(
-            "encode",
-            plan_key=(n, e, int(chunk_size)) + _coding_key(coding),
-            shape=(int(signals.shape[0]), int(signals.shape[1])),
+            "encode", plan_key=(int(l_max), c), shape=(int(k), int(sp)),
         )
-        block_rows = tuned.get("block_rows", 1)
-    return _ef.encode_fused(
-        signals,
-        counts,
+        block_lanes = tuned.get("block_lanes", _ef.BLOCK_LANES)
+    interpret = _interp()
+    if not interpret:
+        check_encode_vmem(c, words, min(int(block_lanes), k * b))
+    # slot-major chunk layout: [K, Sp] -> [K*B, C] -> [C, K*B]
+    s = jnp.where(valid, syms.astype(jnp.int32), -1)
+    s = jnp.pad(s, ((0, 0), (0, b * c - sp)), constant_values=-1)
+    hi, lo, sl, wpc = _ef.encode_pack(
+        s.reshape(k * b, c).T,
         tables.codes,
         tables.lengths,
-        tables.quant.zone,
-        tables.quant.scale,
-        tables.quant.mu,
-        tables.quant.alpha1,
-        basis,
-        n=n,
-        e=e,
-        chunk_size=chunk_size,
-        check_gaps=check_gaps,
-        coding=coding,
-        block_rows=int(block_rows),
-        interpret=_interp(),
+        word_slots=words,
+        block_lanes=int(block_lanes),
+        vmem_limit_bytes=None if interpret else VMEM_LIMIT_BYTES,
+        interpret=interpret,
     )
+
+    def parts(x):  # [words, K*B] -> [K, B, C], zero word slots past words
+        x = jnp.pad(x.T, ((0, 0), (0, c - words)))
+        return x.reshape(k, b, c)
+
+    return parts(hi), parts(lo), parts(sl), wpc.reshape(k, b)
 
 
 def idct_dequant(
     levels: jnp.ndarray,
-    quant: QuantTable,
+    lut: jnp.ndarray,  # f32[E, 256] quant_grid reconstruction LUT
+    rscale: jnp.ndarray,  # f32[] recon_scale of the quant table
     *,
     n: int,
-    basis: jnp.ndarray = None,
 ) -> jnp.ndarray:
-    """Fused dequant + inverse DCT: [W, E] levels -> [W, N] samples.
-
-    ``basis`` lets callers with a persistent decode plan (serving.batch_decode)
-    pass an already-device-resident iDCT basis instead of re-deriving it here.
-    """
-    e = levels.shape[-1]
-    if basis is None:
-        basis = _dct.idct_basis(n, e)
-    return _idq.idct_dequant(
-        levels,
-        quant.zone,
-        quant.scale,
-        basis,
-        quant.mu,
-        quant.alpha1,
-        n=n,
-        interpret=_interp(),
-    )
+    """Fused LUT dequant + inverse DCT: [W, E] levels -> [W, N] samples."""
+    return _idq.idct_dequant(levels, lut, rscale, n=n, interpret=_interp())
 
 
 def dct_quant(
@@ -305,22 +372,20 @@ def dct_quant(
 ) -> jnp.ndarray:
     """Fused forward DCT + quantize: [W, N] samples -> [W, E] levels.
 
-    ``basis`` lets callers with a persistent encode plan (the serving
-    engines) pass the already-device-resident DCT basis instead of
-    re-deriving it here; ``exact=True`` selects the reference-parity
-    quantization arm (bit-identical levels to ``core.quantize.quantize`` —
-    what the fixed-rate workload path pins its byte-identity tests on).
+    ``basis`` lets callers with a persistent encode plan pass the
+    already-device-resident DCT basis (used by the approximate arm);
+    ``exact=True`` selects the reference-parity arm — the backend-exact
+    split DCT and the threshold quantizer traced in-kernel, bit-identical
+    levels to ``core.quantize.quantize(core.dct.forward_dct(...))`` (what
+    the fixed-rate workload path pins its byte-identity tests on).
     """
     n = windows.shape[-1]
     if basis is None:
         basis = _dct.dct_basis(n, e)
     return _dq.dct_quant(
         windows,
-        quant.zone,
-        quant.scale,
+        quant,
         basis,
-        quant.mu,
-        quant.alpha1,
         e=e,
         interpret=_interp(),
         exact=exact,

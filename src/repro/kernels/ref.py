@@ -10,7 +10,12 @@ import jax.numpy as jnp
 
 from repro.core import dct as _dct
 from repro.core import symlen as _symlen
-from repro.core.quantize import QuantTable, dequantize, quantize
+from repro.core.quantize import (
+    QuantTable,
+    dequantize,
+    quantize,
+    recon_scale,
+)
 
 __all__ = ["huffman_decode_padded_ref", "idct_dequant_ref", "dct_quant_ref"]
 
@@ -48,7 +53,7 @@ def huffman_decode_padded_ref(
 def idct_dequant_ref(levels, quant_table: QuantTable, *, n: int):
     """[W, E] uint8/int32 levels -> [W, N] reconstructed samples."""
     coeffs = dequantize(levels.astype(jnp.uint8), quant_table)
-    return _dct.inverse_dct(coeffs, n)
+    return _dct.inverse_dct(coeffs, n, scale=recon_scale(quant_table))
 
 
 def dct_quant_ref(windows, quant_table: QuantTable, *, e: int):

@@ -57,6 +57,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from repro.core.container import ContainerFormatError
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.frontend import (
     DeadlineExpiredError,
     DispatchFailedError,
@@ -75,7 +76,11 @@ from repro.serving.traffic import (
 )
 
 
-def build_frontend(args, fault_injector=None) -> ServingFrontend:
+def build_frontend(
+    args, fault_injector=None, devices="auto"
+) -> ServingFrontend:
+    """The service's front-end from parsed :func:`parse_args` options;
+    ``devices`` is the engines' shard set (``"auto"``: every local chip)."""
     tables = build_domain_tables(seed=args.seed)
     return ServingFrontend(
         tables,
@@ -89,7 +94,7 @@ def build_frontend(args, fault_injector=None) -> ServingFrontend:
             watchdog_timeout_ms=args.watchdog_ms,
         ),
         pipeline=not args.no_pipeline,
-        devices="auto",
+        devices=devices,
         fault_injector=fault_injector,
     )
 
@@ -271,7 +276,7 @@ def run_replay(frontend: ServingFrontend, args) -> None:
     print(f"  {'max inflight':>16}: {stats.max_inflight}")
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--replay", action="store_true",
                     help="synthetic open-loop traffic instead of HTTP")
@@ -298,7 +303,12 @@ def main(argv=None):
     if args.smoke:
         args.rate, args.duration = 50.0, 0.5
         args.replay = True
+    return args
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
     frontend = build_frontend(args)
     if args.replay:
         run_replay(frontend, args)

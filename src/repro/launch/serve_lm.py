@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_arch, get_smoke
 from repro.distributed.train import make_serve_fns
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models.common import init_params
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--model-par", type=int, default=1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     model = build_model(cfg)

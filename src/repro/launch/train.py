@@ -25,6 +25,7 @@ from repro.distributed.compression import CompressionConfig
 from repro.distributed.elastic import StepTimer
 from repro.distributed.optimizer import AdamW, AdamWConfig
 from repro.distributed.train import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models.common import init_params
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--model-par", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     model = build_model(cfg)

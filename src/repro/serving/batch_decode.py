@@ -24,7 +24,7 @@ This module removes all three:
     segment-aware scatter over one exclusive prefix-sum of the concatenated
     symlen sidecar (``core.symlen.compact_padded_scatter``) — container
     boundaries fall out of the segment sums for free.
-  * **Persistent decode plans.**  Device tables and the iDCT basis upload
+  * **Persistent decode plans.**  Device tables and the dequant LUT upload
     once per (domain, config, shard device) into an LRU :class:`DecodePlan`
     cache; decoded samples stay on device inside a :class:`DecodedBatch`
     until an explicit ``.to_host()`` drains them.
@@ -68,6 +68,7 @@ from repro.core.container import Container
 from repro.core.quantize import (
     expand_coded_stream,
     quant_grid,
+    recon_scale,
     unpredict_levels,
 )
 from repro.serving._plans import (
@@ -111,15 +112,16 @@ TablesArg = Union[DomainTables, Mapping[int, DomainTables]]
 class DecodePlan:
     """Device-resident decode state for one (domain, config) on one shard.
 
-    Holds the Huffman/quant tables and the iDCT basis as device arrays plus
-    the statics that specialize the fused decode.  Everything here is
+    Holds the Huffman/quant tables, the dequant LUT and its reconstruction
+    bound as device arrays plus the statics that specialize the fused
+    decode.  Everything here is
     batch-size independent: one plan serves every bucket shape on its
     device (``device=None`` is the single-shard default placement).
     """
 
     tables: DeviceTables
-    basis: jnp.ndarray  # f32[E, N]
     lut: jnp.ndarray  # f32[E, 256] — quant_grid reconstruction LUT
+    rscale: jnp.ndarray  # f32[] — recon_scale bound the iDCT splits against
     n: int
     e: int
     l_max: int
@@ -134,7 +136,7 @@ class DecodePlan:
 def _build_decode_plan(tables: DomainTables, key, device) -> DecodePlan:
     domain_id, n, e, l_max, coding = normalize_plan_key(key)
     dev_tables = tables.device_tables()
-    basis = dct.idct_basis(n, e)
+    rscale = recon_scale(tables.quant)
     # the 256-level reconstruction LUT (quant_grid): dequantization becomes
     # an exact selection instead of per-symbol transcendentals, and —
     # because the fused Pallas kernel and the XLA path select from the SAME
@@ -142,12 +144,12 @@ def _build_decode_plan(tables: DomainTables, key, device) -> DecodePlan:
     lut, _ = quant_grid(tables.quant)
     if device is not None:
         dev_tables = jax.device_put(dev_tables, device)
-        basis = jax.device_put(basis, device)
+        rscale = jax.device_put(rscale, device)
         lut = jax.device_put(lut, device)
     return DecodePlan(
         tables=dev_tables,
-        basis=basis,
         lut=lut,
+        rscale=rscale,
         n=n,
         e=e,
         l_max=l_max,
@@ -167,7 +169,7 @@ def _decode_bucket_math(
     sl: jnp.ndarray,  # int32[Wp]    (0 on padding words)
     tables: DeviceTables,
     lut: jnp.ndarray,  # f32[E, 256] quant_grid reconstruction LUT
-    basis: jnp.ndarray,  # f32[E, N]
+    rscale: jnp.ndarray,  # f32[] recon_scale of the plan's quant table
     v3: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     *,
     l_max: int,
@@ -225,7 +227,7 @@ def _decode_bucket_math(
         from repro.kernels import ops as kops
 
         return kops.decode_bucket_fused(
-            hi, lo, sl, tables, lut, basis, v3,
+            hi, lo, sl, tables, lut, rscale, v3,
             l_max=l_max, max_symlen=max_symlen, num_windows=num_windows,
             n=n, e=e, coding=coding,
         )
@@ -244,7 +246,7 @@ def _decode_bucket_math(
             grid.astype(jnp.uint32), seg, pred_id, bands
         ).astype(jnp.int32)
     coeffs = lut[jnp.arange(e, dtype=jnp.int32)[None, :], levels]
-    return coeffs @ basis
+    return dct.inverse_dct(coeffs, n, scale=rscale)
 
 
 _decode_bucket = functools.partial(
@@ -277,33 +279,32 @@ def bucket_cache_size() -> Optional[int]:
 def _decode_fixed_math(
     levels: jnp.ndarray,  # uint8[..., W, E]
     lut: jnp.ndarray,  # f32[E, 256]
-    basis: jnp.ndarray,  # f32[E, N]
+    rscale: jnp.ndarray,  # f32[] recon_scale
     *,
+    n: int,
     e: int,
 ) -> jnp.ndarray:
     idx = levels.astype(jnp.int32)
     coeffs = lut[jnp.arange(e, dtype=jnp.int32), idx]
-    windows = coeffs @ basis  # [..., W, N]
+    windows = dct.inverse_dct(coeffs, n, scale=rscale)  # [..., W, N]
     return windows.reshape(windows.shape[:-2] + (-1,))
 
 
 _decode_fixed = functools.partial(
-    jax.jit, static_argnames=("e",)
+    jax.jit, static_argnames=("n", "e")
 )(_decode_fixed_math)
 
 
 def _decode_fixed_kernels_math(
-    levels, tables, basis, *, n, e, tuning_epoch=0
+    levels, lut, rscale, *, n, e, tuning_epoch=0
 ):
-    # the staged Pallas dequant+iDCT tile; it dequantizes in-kernel (not
-    # from the LUT), so floats agree with the XLA arm to ~1e-5 — the
-    # fixed-rate byte contract lives on the ENCODE side (levels), where the
-    # exact-parity arm is bit-identical
+    # the staged Pallas dequant+iDCT tile: the same LUT selection and split
+    # iDCT as the XLA arm, so the samples are the same bits
     del tuning_epoch
     from repro.kernels import ops as kops
 
     flat = levels.reshape(-1, e).astype(jnp.int32)
-    windows = kops.idct_dequant(flat, tables.quant, n=n, basis=basis)
+    windows = kops.idct_dequant(flat, lut, rscale, n=n)
     return windows.reshape(levels.shape[:-2] + (-1,))
 
 
@@ -653,7 +654,7 @@ class BatchDecoder:
         Dequantization is an exact selection from the plan's 256-level
         ``quant_grid`` LUT — the same values the container decode path
         reconstructs — followed by the MXU iDCT.  Everything stays device-
-        resident; tables/basis/LUT ride the persistent :class:`DecodePlan`
+        resident; tables/LUT ride the persistent :class:`DecodePlan`
         cache, so repeated cold-block reads pay zero re-uploads.
         """
         cfg = tables.config
@@ -666,11 +667,11 @@ class BatchDecoder:
             )
         if self.use_kernels:
             x = _decode_fixed_kernels(
-                levels, plan.tables, plan.basis, n=n, e=e,
+                levels, plan.lut, plan.rscale, n=n, e=e,
                 tuning_epoch=_autotune.epoch(),
             )
         else:
-            x = _decode_fixed(levels, plan.lut, plan.basis, e=e)
+            x = _decode_fixed(levels, plan.lut, plan.rscale, n=n, e=e)
         self.stats.dispatches += 1
         if length is not None:
             x = x[..., :length]
@@ -798,7 +799,7 @@ class BatchDecoder:
             grp = g() if callable(g) else g
             put = putter(grp.device)
             # shard-aware plan prefetch: build/upload this bucket's decode
-            # plan (tables + basis + LUT device_put) from the staging
+            # plan (tables + LUT device_put) from the staging
             # worker, so the first dispatch on each shard doesn't pay it —
             # PlanCache.get is thread-safe and the factory only transfers
             self._plan_for_key(tuple(grp.plan_key), tables, grp.device)
@@ -836,7 +837,7 @@ class BatchDecoder:
                 grp.symlen,
                 plan.tables,
                 plan.lut,
-                plan.basis,
+                plan.rscale,
                 v3,
                 l_max=plan.l_max,
                 max_symlen=symlen_bucket(grp.max_symlen),

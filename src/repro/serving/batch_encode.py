@@ -123,7 +123,6 @@ class EncodePlan:
     """
 
     tables: DeviceTables
-    basis: jnp.ndarray  # f32[N, E] dct basis — the fused kernel's operand
     n: int
     e: int
     l_max: int
@@ -139,13 +138,10 @@ class EncodePlan:
 def _build_encode_plan(tables: DomainTables, key, device) -> EncodePlan:
     domain_id, n, e, l_max, coding = normalize_plan_key(key)
     dev_tables = tables.device_tables()
-    basis = dct.dct_basis(n, e)
     if device is not None:
         dev_tables = jax.device_put(dev_tables, device)
-        basis = jax.device_put(basis, device)
     return EncodePlan(
         tables=dev_tables,
-        basis=basis,
         n=n,
         e=e,
         l_max=l_max,
@@ -160,6 +156,69 @@ def _build_encode_plan(tables: DomainTables, key, device) -> EncodePlan:
 # ---------------------------------------------------------------------------
 # The fused bucket encode — ONE jit specialization per bucket shape.
 # ---------------------------------------------------------------------------
+def _encode_symbols(
+    signals: jnp.ndarray,  # f32[K, Wp * n] (zero-padded signals)
+    counts: jnp.ndarray,  # int32[K] true symbol count per signal
+    tables: DeviceTables,
+    *,
+    n: int,
+    e: int,
+    check_gaps: bool,
+    coding: Tuple[int, int, bool] = TRIVIAL_CODING,
+):
+    """The front half of a bucket encode: DCT + quantize (+ the v3 coding
+    stage) down to the symbols the packer consumes.
+
+    Returns ``(syms int32[K, Sp], valid bool[K, Sp], bad bool[K], extras)``
+    — ``valid`` masks the slots that pack (true windows, minus v3
+    zero-plane cells), ``bad`` the per-row unencodable-symbol flags (const
+    False unless the book has histogram gaps), and ``extras`` the v3
+    outputs ``(ncoded, zrow, zcol)`` (empty for the v2 stream).  Both pack
+    arms — the XLA chunk packer and the Pallas pack kernel — consume
+    exactly these arrays, which is what makes them byte-identical.
+    """
+    windows = dct.window_signal(signals, n)  # [K, Wp, n]
+    coeffs = dct.forward_dct(windows, e)  # [K, Wp, e]
+    grid = quantize(coeffs, tables.quant)  # uint8[K, Wp, e]
+    k = signals.shape[0]
+    if coding == TRIVIAL_CODING:
+        syms = grid.reshape(k, -1).astype(jnp.int32)  # [K, Sp]
+        valid = (
+            jnp.arange(syms.shape[1], dtype=jnp.int32)[None, :]
+            < counts[:, None]
+        )
+        extras = ()
+    else:
+        pred_id, bands, zplanes = coding
+        grid = predict_levels(grid, pred_id, bands)  # uint8[K, Wp, e]
+        syms = grid.reshape(k, -1).astype(jnp.int32)  # [K, Sp]
+        # true-window mask: batch/window padding quantizes to 128 but its
+        # *residuals* need not be 128, so every v3 mask is gated on it
+        win_valid = (
+            jnp.arange(grid.shape[1], dtype=jnp.int32)[None, :]
+            < (counts // e)[:, None]
+        )  # bool[K, Wp]
+        if zplanes:
+            is_zero = grid == jnp.uint8(128)
+            # zrow over all rows (padding rows are garbage but the drain
+            # slices mask[:num_windows]); zcol over VALID rows only,
+            # matching the host encoder's grid which has num_windows rows
+            zrow = jnp.all(is_zero, axis=2)  # bool[K, Wp]
+            zcol = jnp.all(is_zero | ~win_valid[:, :, None], axis=1)
+            valid = (win_valid & ~zrow)[:, :, None] & ~zcol[:, None, :]
+            valid = valid.reshape(k, -1)
+            extras = (jnp.sum(valid, axis=1, dtype=jnp.int32), zrow, zcol)
+        else:
+            valid = jnp.broadcast_to(win_valid[:, :, None], grid.shape)
+            valid = valid.reshape(k, -1)
+            extras = (counts, None, None)
+    if check_gaps:
+        bad = jnp.any((tables.lengths[syms] == 0) & valid, axis=1)
+    else:
+        bad = jnp.zeros((k,), jnp.bool_)
+    return syms, valid, bad, extras
+
+
 def _encode_bucket_math(
     signals: jnp.ndarray,  # f32[K, Wp * n] (zero-padded signals)
     counts: jnp.ndarray,  # int32[K] true symbol count per signal
@@ -195,68 +254,16 @@ def _encode_bucket_math(
     return adds per-signal coded-symbol counts and — under zero planes —
     the row/column bitmaps: ``(hi, lo, sl, wpc, bad, ncoded, zrow, zcol)``.
     """
-    windows = dct.window_signal(signals, n)  # [K, Wp, n]
-    coeffs = dct.forward_dct(windows, e)  # [K, Wp, e]
-    syms = quantize(coeffs, tables.quant)  # uint8[K, Wp, e]
-    k = signals.shape[0]
-    if coding == TRIVIAL_CODING:
-        syms = syms.reshape(k, -1).astype(jnp.int32)  # [K, Sp]
-        if check_gaps:
-            valid = (
-                jnp.arange(syms.shape[1], dtype=jnp.int32)[None, :]
-                < counts[:, None]
-            )
-            bad = jnp.any((tables.lengths[syms] == 0) & valid, axis=1)
-        else:
-            bad = jnp.zeros((k,), jnp.bool_)
-        hi, lo, sl, wpc = jax.vmap(
-            lambda s, c: symlen.pack_symlen_chunked_parts(
-                s,
-                tables.codes,
-                tables.lengths,
-                chunk_size=chunk_size,
-                num_symbols=c,
-            )
-        )(syms, counts)
-        return hi, lo, sl, wpc, bad
-    pred_id, bands, zplanes = coding
-    grid = predict_levels(syms, pred_id, bands)  # uint8[K, Wp, e]
-    flat = grid.reshape(k, -1).astype(jnp.int32)  # [K, Sp]
-    # true-window mask: batch/window padding quantizes to 128 but its
-    # *residuals* need not be 128, so every v3 mask is gated on it
-    win_valid = (
-        jnp.arange(grid.shape[1], dtype=jnp.int32)[None, :]
-        < (counts // e)[:, None]
-    )  # bool[K, Wp]
-    if zplanes:
-        is_zero = grid == jnp.uint8(128)
-        # zrow over all rows (padding rows are garbage but the drain slices
-        # mask[:num_windows]); zcol over VALID rows only, matching the host
-        # encoder's grid which has exactly num_windows rows
-        zrow = jnp.all(is_zero, axis=2)  # bool[K, Wp]
-        zcol = jnp.all(is_zero | ~win_valid[:, :, None], axis=1)  # [K, e]
-        valid = (win_valid & ~zrow)[:, :, None] & ~zcol[:, None, :]
-        valid = valid.reshape(k, -1)
-        ncoded = jnp.sum(valid, axis=1, dtype=jnp.int32)
-    else:
-        zrow = zcol = None
-        valid = jnp.broadcast_to(win_valid[:, :, None], grid.shape)
-        valid = valid.reshape(k, -1)
-        ncoded = counts
-    if check_gaps:
-        bad = jnp.any((tables.lengths[flat] == 0) & valid, axis=1)
-    else:
-        bad = jnp.zeros((k,), jnp.bool_)
+    syms, valid, bad, extras = _encode_symbols(
+        signals, counts, tables, n=n, e=e, check_gaps=check_gaps,
+        coding=coding,
+    )
     hi, lo, sl, wpc = jax.vmap(
         lambda s, v: symlen.pack_symlen_chunked_parts(
-            s,
-            tables.codes,
-            tables.lengths,
-            chunk_size=chunk_size,
-            valid=v,
+            s, tables.codes, tables.lengths, chunk_size=chunk_size, valid=v,
         )
-    )(flat, valid)
-    return hi, lo, sl, wpc, bad, ncoded, zrow, zcol
+    )(syms, valid)
+    return (hi, lo, sl, wpc, bad) + extras
 
 
 _encode_bucket = functools.partial(
@@ -339,38 +346,42 @@ def _donation_supported(device) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# The kernel-path twins: the fused Pallas encode tile instead of the XLA
-# DCT+quant+pack — bit-identical output (pinned by the golden/conformance
-# suites), one pallas_call per bucket.
+# The kernel-path twins: the same DCT + quantize front half, then the
+# Pallas pack kernel instead of the XLA chunk packer — byte-identical chunk
+# parts (pinned by the golden/conformance suites), one pallas_call per
+# bucket.
 # ---------------------------------------------------------------------------
 def _encode_bucket_kernels_math(
-    signals, counts, tables, basis, *, n, e, chunk_size, check_gaps,
+    signals, counts, tables, *, n, e, l_max, chunk_size, check_gaps,
     coding=TRIVIAL_CODING, tuning_epoch=0,
 ):
     # tuning_epoch is a pure retrace key (see batch_decode._decode_bucket):
-    # the kernel resolves its rows-per-step block from the tuning cache at
+    # the kernel resolves its lanes-per-step block from the tuning cache at
     # trace time, so a cache store must invalidate old specializations
     del tuning_epoch
     from repro.kernels import ops as kops
 
-    return kops.encode_bucket_fused(
-        signals, counts, tables, basis,
-        n=n, e=e, chunk_size=chunk_size, check_gaps=check_gaps,
+    syms, valid, bad, extras = _encode_symbols(
+        signals, counts, tables, n=n, e=e, check_gaps=check_gaps,
         coding=coding,
     )
+    hi, lo, sl, wpc = kops.encode_bucket_fused(
+        syms, valid, tables, chunk_size=chunk_size, l_max=l_max,
+    )
+    return (hi, lo, sl, wpc, bad) + extras
 
 
+_KERNEL_STATICS = (
+    "n", "e", "l_max", "chunk_size", "check_gaps", "coding", "tuning_epoch"
+)
 _encode_bucket_kernels = functools.partial(
-    jax.jit,
-    static_argnames=(
-        "n", "e", "chunk_size", "check_gaps", "coding", "tuning_epoch"
-    ),
+    jax.jit, static_argnames=_KERNEL_STATICS
 )(_encode_bucket_kernels_math)
 
 
 def _encode_bucket_gather_kernels_math(
-    flat, starts, lens, counts, tables, basis,
-    *, width, n, e, chunk_size, check_gaps, coding=TRIVIAL_CODING,
+    flat, starts, lens, counts, tables,
+    *, width, n, e, l_max, chunk_size, check_gaps, coding=TRIVIAL_CODING,
     tuning_epoch=0,
 ):
     """GatherStage staging for the kernel path: the row gather stays an XLA
@@ -379,13 +390,13 @@ def _encode_bucket_gather_kernels_math(
     separately-dispatched signal matrix)."""
     x = _gather_rows_math(flat, starts, lens, width)
     return _encode_bucket_kernels_math(
-        x, counts, tables, basis,
-        n=n, e=e, chunk_size=chunk_size, check_gaps=check_gaps,
-        coding=coding, tuning_epoch=tuning_epoch,
+        x, counts, tables,
+        n=n, e=e, l_max=l_max, chunk_size=chunk_size,
+        check_gaps=check_gaps, coding=coding, tuning_epoch=tuning_epoch,
     )
 
 
-_GATHER_KERNEL_STATICS = _GATHER_STATICS + ("tuning_epoch",)
+_GATHER_KERNEL_STATICS = _KERNEL_STATICS + ("width",)
 _encode_bucket_gather_kernels = functools.partial(
     jax.jit, static_argnames=_GATHER_KERNEL_STATICS
 )(_encode_bucket_gather_kernels_math)
@@ -426,7 +437,7 @@ _encode_fixed = functools.partial(
 
 
 def _encode_fixed_kernels_math(
-    x, tables, basis, *, n, e, tuning_epoch=0
+    x, tables, *, n, e, tuning_epoch=0
 ):
     # the Pallas DCT+quant tile with the exact-parity quantization arm:
     # levels are BIT-identical to the XLA arm (pinned in test_workloads),
@@ -436,8 +447,7 @@ def _encode_fixed_kernels_math(
 
     w = x.shape[-1] // n
     windows = x.reshape(-1, n)
-    levels = kops.dct_quant(windows, tables.quant, e=e, basis=basis,
-                            exact=True)
+    levels = kops.dct_quant(windows, tables.quant, e=e, exact=True)
     return levels.astype(jnp.uint8).reshape(x.shape[:-1] + (w, e))
 
 
@@ -883,7 +893,7 @@ class BatchEncoder:
         x = jnp.asarray(x, jnp.float32)
         if self.use_kernels:
             levels = _encode_fixed_kernels(
-                x, plan.tables, plan.basis, n=n, e=e,
+                x, plan.tables, n=n, e=e,
                 tuning_epoch=_autotune.epoch(),
             )
         else:
@@ -1064,7 +1074,7 @@ class BatchEncoder:
                     )
                     out = fused(
                         x.flat, x.starts, x.lens, counts, plan.tables,
-                        plan.basis, width=wp * n, n=n, e=e,
+                        width=wp * n, n=n, e=e, l_max=plan.l_max,
                         chunk_size=chunk, check_gaps=plan.has_gaps,
                         coding=coding, tuning_epoch=_autotune.epoch(),
                     )
@@ -1081,8 +1091,9 @@ class BatchEncoder:
                 kp = int(x.starts.shape[0])
             elif self.use_kernels:
                 out = _encode_bucket_kernels(
-                    x, counts, plan.tables, plan.basis,
-                    n=n, e=e, chunk_size=chunk, check_gaps=plan.has_gaps,
+                    x, counts, plan.tables,
+                    n=n, e=e, l_max=plan.l_max, chunk_size=chunk,
+                    check_gaps=plan.has_gaps,
                     coding=coding, tuning_epoch=_autotune.epoch(),
                 )
                 kp = int(x.shape[0])

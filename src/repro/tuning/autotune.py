@@ -288,7 +288,7 @@ def decode_block_candidates(
     kernel) and deduplicated."""
     out: List[Blocks] = []
     seen = set()
-    for bw in (256, 512, 1024, 2048):
+    for bw in (1024, 2048, 4096):
         for bn in (128, 256, 512):
             cand = (
                 min(bw, max(int(words), 1)),
@@ -301,15 +301,17 @@ def decode_block_candidates(
     return out
 
 
-def encode_block_candidates(rows: int) -> List[Blocks]:
+def encode_block_candidates(lanes: int) -> List[Blocks]:
+    """Default encode sweep grid: chunks packed per grid step (a lane
+    block of 128-multiples, or the whole bucket when it is narrower)."""
     out: List[Blocks] = []
     seen = set()
-    for br in (1, 2, 4, 8):
-        r = min(br, max(int(rows), 1))
-        if r in seen:
+    for lb in (128, 256, 512, 1024):
+        b = min(lb, max(int(lanes), 1))
+        if b in seen:
             continue
-        seen.add(r)
-        out.append({"block_rows": r})
+        seen.add(b)
+        out.append({"block_lanes": b})
     return out
 
 
@@ -458,8 +460,7 @@ def tune_decode_bucket(
     (plan key, bucket shape); interpret mode on CPU, real on TPU."""
     import jax.numpy as jnp
 
-    from repro.core import dct
-    from repro.core.quantize import quant_grid
+    from repro.core.quantize import quant_grid, recon_scale
     from repro.kernels import ops as kops
     from repro.serving.engine import symlen_bucket
 
@@ -467,7 +468,7 @@ def tune_decode_bucket(
     hi, lo, sl, max_sl = _synthetic_stream(tables, num_words, num_windows)
     dev_tables = tables.device_tables()
     lut, _ = quant_grid(tables.quant)
-    basis = dct.idct_basis(cfg.n, cfg.e)
+    rscale = recon_scale(tables.quant)
     hi, lo, sl = jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(sl)
     ms = symlen_bucket(max_sl)
     # the EXACT key ops.decode_bucket_fused consults at trace time — block
@@ -476,7 +477,7 @@ def tune_decode_bucket(
 
     def runner(blocks: Blocks) -> None:
         out = kops.decode_bucket_fused(
-            hi, lo, sl, dev_tables, lut, basis,
+            hi, lo, sl, dev_tables, lut, rscale,
             l_max=cfg.l_max, max_symlen=ms, num_windows=num_windows,
             n=cfg.n, e=cfg.e,
             block_words=blocks["block_words"],
@@ -509,46 +510,48 @@ def tune_encode_bucket(
     force: bool = False,
     top_k: Optional[int] = None,
 ) -> Blocks:
-    """Sweep the encode megakernel's rows-per-grid-step for one
+    """Sweep the encode pack kernel's chunks-per-grid-step for one
     (plan key, bucket shape)."""
     import numpy as np
 
     import jax.numpy as jnp
 
-    from repro.core import dct
     from repro.kernels import ops as kops
+    from repro.serving.batch_encode import _encode_symbols
 
     cfg = tables.config
     width = num_windows * cfg.n
     sp = num_windows * cfg.e
     chunk = sp if chunk_size is None else min(int(chunk_size), sp)
     # the EXACT key ops.encode_bucket_fused consults at trace time
-    plan_key = (cfg.n, cfg.e, chunk)
+    plan_key = (cfg.l_max, chunk)
     rng = np.random.default_rng(11)
     signals = jnp.asarray(
         rng.standard_normal((rows, width)).astype(np.float32)
     )
     counts = jnp.full((rows,), sp, dtype=jnp.int32)
     dev_tables = tables.device_tables()
-    basis = dct.dct_basis(cfg.n, cfg.e)
+    syms, valid, _, _ = _encode_symbols(
+        signals, counts, dev_tables, n=cfg.n, e=cfg.e, check_gaps=False,
+    )
+    lanes = rows * -(-sp // chunk)
 
     def runner(blocks: Blocks) -> None:
         out = kops.encode_bucket_fused(
-            signals, counts, dev_tables, basis,
-            n=cfg.n, e=cfg.e, chunk_size=chunk, check_gaps=False,
-            block_rows=blocks["block_rows"],
+            syms, valid, dev_tables, chunk_size=chunk, l_max=cfg.l_max,
+            block_lanes=blocks["block_lanes"],
         )
         out[3].block_until_ready()
 
     rank = None
     if cost_model is not None:
         rank = lambda b: cost_model.encode_bucket_cost(  # noqa: E731
-            rows, num_windows, e=cfg.e, n=cfg.n,
-            block_rows=b["block_rows"],
+            rows, num_windows, e=cfg.e, n=cfg.n, chunk_size=chunk,
+            block_lanes=b["block_lanes"],
         )
     return tune(
-        "encode", plan_key, (rows, width),
-        runner, encode_block_candidates(rows),
+        "encode", plan_key, (rows, sp),
+        runner, encode_block_candidates(lanes),
         cache=cache, trials=trials, force=force, rank=rank, top_k=top_k,
     )
 

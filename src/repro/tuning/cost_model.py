@@ -1,4 +1,4 @@
-"""Per-backend dispatch cost model for the serving engines' shape decisions.
+"""Per-device dispatch cost model for the serving engines' shape decisions.
 
 The model predicts the wall cost of one fused bucket dispatch from
 (words, windows, batch, block sizes, backend) with a plain roofline:
@@ -37,32 +37,63 @@ import threading
 from collections import deque
 from typing import Dict, Optional, Tuple
 
-__all__ = ["BackendProfile", "CostModel", "default_cost_model"]
+__all__ = ["BackendProfile", "CostModel", "PEAKS", "default_cost_model"]
 
 
 @dataclasses.dataclass(frozen=True)
 class BackendProfile:
-    """Static roofline numbers for one backend.
+    """Roofline numbers for one device kind.
 
-    The defaults are deliberately coarse (order-of-magnitude peaks for a
-    server CPU, an A100-class GPU and a v5e-class TPU); they set the
-    *ratios* between compute, memory and launch overhead that the policy
-    and tuner decisions depend on, and timing calibration absorbs the rest.
+    ``peak_flops``/``hbm_bps`` are the device's published peaks (the
+    ``source`` says where from); the three overheads are coarse launch and
+    compile costs that set the *ratios* the policy and tuner decisions
+    depend on, and timing calibration absorbs the rest.
     """
 
-    backend: str
+    device_kind: str
     peak_flops: float  # FLOP/s
     hbm_bps: float  # bytes/s
     dispatch_overhead_s: float  # per fused dispatch (host->device launch)
     step_overhead_s: float  # per grid step inside a kernel
     compile_cost_s: float  # per new jit specialization
+    source: str
 
 
-_PROFILES: Dict[str, BackendProfile] = {
-    "cpu": BackendProfile("cpu", 5e10, 2e10, 3e-5, 2e-7, 0.5),
-    "gpu": BackendProfile("gpu", 2e13, 1.5e12, 1e-5, 5e-8, 0.8),
-    "tpu": BackendProfile("tpu", 2e14, 8e11, 2e-6, 2e-8, 1.0),
+_V5E = dict(
+    peak_flops=197e12, hbm_bps=819e9, dispatch_overhead_s=2e-6,
+    step_overhead_s=2e-8, compile_cost_s=1.0,
+    source="Google Cloud TPU v5e documentation: 197 TFLOP/s bf16, "
+    "819 GB/s HBM per chip",
+)
+
+# Peaks by ``jax.devices()[0].device_kind``.  A device kind that is not
+# here is an error, never a default: a wrong roofline silently skews every
+# shard split and tuner ranking built on it.
+PEAKS: Dict[str, BackendProfile] = {
+    "cpu": BackendProfile(
+        "cpu", 5e10, 2e10, 3e-5, 2e-7, 0.5,
+        source="order-of-magnitude server CPU figures (test backend)",
+    ),
+    "TPU v5 lite": BackendProfile("TPU v5 lite", **_V5E),  # TPU v5e
 }
+
+
+def device_profile(device_kind: Optional[str] = None) -> BackendProfile:
+    """The :data:`PEAKS` entry for ``device_kind`` (default: the first
+    visible device's); raises ``KeyError`` for a kind not in the table."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no roofline peaks for device kind {device_kind!r}; add its "
+            f"published figures to repro.tuning.cost_model.PEAKS (known: "
+            f"{sorted(PEAKS)})"
+        ) from None
+
 
 # analytic per-unit op counts, mirroring the traced kernels:
 #   huffman slot step: ~l_max compare/shift ops per (word, slot) iteration
@@ -94,14 +125,10 @@ class CostModel:
         self,
         profile: Optional[BackendProfile] = None,
         *,
-        backend: Optional[str] = None,
+        device_kind: Optional[str] = None,
     ):
         if profile is None:
-            if backend is None:
-                import jax
-
-                backend = jax.default_backend()
-            profile = _PROFILES.get(backend, _PROFILES["cpu"])
+            profile = device_profile(device_kind)
         self.profile = profile
         self._lock = threading.Lock()
         # kind -> (flops scale, bytes scale) from HLO/jaxpr seeding
@@ -250,14 +277,20 @@ class CostModel:
         *,
         e: int,
         n: int,
-        block_rows: int = 1,
+        chunk_size: int = 1024,
+        block_lanes: int = 128,
     ) -> float:
         """Predicted seconds for one fused encode dispatch: ``rows``
         (batch-padded) signal rows of ``windows_per_row`` windows each,
-        ``block_rows`` rows per grid step."""
-        block_rows = min(max(block_rows, 1), max(rows, 1))
-        kp = _round_up(max(rows, 1), block_rows)
-        steps = _ceil_div(kp, block_rows)
+        packed as ``chunk_size``-slot chunks, ``block_lanes`` chunks per
+        grid step (the lane axis pads to a block multiple, as the pack
+        kernel pads it)."""
+        lanes = max(rows, 1) * _ceil_div(max(windows_per_row * e, 1),
+                                          chunk_size)
+        block_lanes = min(max(block_lanes, 1), lanes)
+        lp = _round_up(lanes, block_lanes)
+        steps = _ceil_div(lp, block_lanes)
+        kp = lp * chunk_size / max(windows_per_row * e, 1)
         sf, sb = self._scales("encode")
         flops = sf * self.encode_flops(kp, windows_per_row, e=e, n=n)
         nbytes = sb * self.encode_bytes(kp, windows_per_row, e=e, n=n)
@@ -343,16 +376,13 @@ _DEFAULTS: Dict[str, CostModel] = {}
 _DEFAULTS_LOCK = threading.Lock()
 
 
-def default_cost_model(backend: Optional[str] = None) -> CostModel:
-    """Process-wide shared model per backend (engines constructed with
+def default_cost_model(device_kind: Optional[str] = None) -> CostModel:
+    """Process-wide shared model per device kind (engines constructed with
     ``cost_model=None`` resolve here, so seeding/calibrating the default
     model steers every default-constructed engine)."""
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
+    profile = device_profile(device_kind)
     with _DEFAULTS_LOCK:
-        cm = _DEFAULTS.get(backend)
+        cm = _DEFAULTS.get(profile.device_kind)
         if cm is None:
-            cm = _DEFAULTS[backend] = CostModel(backend=backend)
+            cm = _DEFAULTS[profile.device_kind] = CostModel(profile)
         return cm

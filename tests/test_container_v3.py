@@ -188,12 +188,12 @@ def test_v3_decode_bucket_is_one_pallas_call(power_tables, coding):
     plan, hi, lo, sl, v3, statics = _v3_bucket_operands(t3)
     fused = jax.make_jaxpr(functools.partial(
         _decode_bucket_math, use_kernels=True, **statics
-    ))(hi, lo, sl, plan.tables, plan.lut, plan.basis, v3)
+    ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3)
     assert _count_eqns(fused.jaxpr, "pallas_call") == 1
 
     unfused = jax.make_jaxpr(functools.partial(
         _decode_bucket_math, use_kernels=False, **statics
-    ))(hi, lo, sl, plan.tables, plan.lut, plan.basis, v3)
+    ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3)
     assert _count_eqns(unfused.jaxpr, "pallas_call") == 0
 
 
@@ -204,11 +204,11 @@ def test_v3_decode_bucket_kernel_bit_identical(power_tables, coding):
     t3 = _retable(power_tables, **coding)
     plan, hi, lo, sl, v3, statics = _v3_bucket_operands(t3)
     ref = _decode_bucket(
-        hi, lo, sl, plan.tables, plan.lut, plan.basis, v3,
+        hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3,
         use_kernels=False, **statics,
     )
     got = _decode_bucket(
-        hi, lo, sl, plan.tables, plan.lut, plan.basis, v3,
+        hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3,
         use_kernels=True, **statics,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -235,9 +235,9 @@ def test_v3_encode_bucket_is_one_pallas_call(power_tables, coding):
     counts = jnp.zeros((2,), jnp.int32)
     traced = jax.make_jaxpr(functools.partial(
         _encode_bucket_kernels_math,
-        n=cfg.n, e=cfg.e, chunk_size=64, check_gaps=True,
+        n=cfg.n, e=cfg.e, l_max=cfg.l_max, chunk_size=64, check_gaps=True,
         coding=cfg.coding,
-    ))(x, counts, plan.tables, plan.basis)
+    ))(x, counts, plan.tables)
     assert _count_eqns(traced.jaxpr, "pallas_call") == 1
 
 
@@ -272,8 +272,8 @@ def test_v3_encode_bucket_kernel_bit_identical(power_tables, coding):
             coding=cfg.coding,
         )
         got = _encode_bucket_kernels(
-            jnp.asarray(x), jnp.asarray(counts), plan.tables, plan.basis,
-            n=n, e=e, chunk_size=chunk, check_gaps=False,
+            jnp.asarray(x), jnp.asarray(counts), plan.tables,
+            n=n, e=e, l_max=cfg.l_max, chunk_size=chunk, check_gaps=False,
             coding=cfg.coding,
         )
         for a, b in zip(ref, got):

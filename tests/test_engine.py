@@ -650,9 +650,8 @@ def test_tuning_cache_warm_vs_cold_byte_identical(tables, archive, tmp_path):
             sp = wb * cfg.e
             kp = enc.scheduler.round(len(members))
             cache.store(
-                "encode", backend, (cfg.n, cfg.e, min(64, sp)),
-                (kp, wb * cfg.n),
-                {"block_rows": 3},  # pads the row axis inside the kernel
+                "encode", backend, (cfg.l_max, min(64, sp)), (kp, sp),
+                {"block_lanes": 3},  # pads the lane axis inside the kernel
             )
         assert autotune.epoch() > e0
 

@@ -138,8 +138,8 @@ def test_jaxpr_fused_decode_megakernel_is_one_dispatch():
     pallas_call with nonzero attributed flops (the HLO parser can't see
     this — in interpret mode the kernel lowers to an unrelated while-nest).
     """
-    from repro.core import DOMAIN_DEFAULTS, calibrate, codec, dct
-    from repro.core.quantize import quant_grid
+    from repro.core import DOMAIN_DEFAULTS, calibrate, codec
+    from repro.core.quantize import quant_grid, recon_scale
     from repro.kernels import ops as kops
     from repro.serving.engine import symlen_bucket
 
@@ -155,11 +155,11 @@ def test_jaxpr_fused_decode_megakernel_is_one_dispatch():
     ms = symlen_bucket(cont.max_symlen)
     dev = tables.device_tables()
     lut, _ = quant_grid(tables.quant)
-    basis = dct.idct_basis(cfg.n, cfg.e)
+    rscale = recon_scale(tables.quant)
 
     def run(hi, lo, sl):
         return kops.decode_bucket_fused(
-            hi, lo, sl, dev, lut, basis,
+            hi, lo, sl, dev, lut, rscale,
             l_max=cfg.l_max, max_symlen=ms,
             num_windows=cont.num_windows, n=cfg.n, e=cfg.e,
         )

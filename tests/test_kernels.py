@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import dct as dctlib
 from repro.core.huffman import build_codebook
-from repro.core.quantize import build_quant_table
+from repro.core.quantize import build_quant_table, quant_grid, recon_scale
 from repro.core.symlen import pack_symlen_np, words_to_u32
 from repro.kernels import ref as kref
 from repro.kernels.dct_quant import dct_quant
@@ -50,7 +50,9 @@ def test_huffman_decode_kernel_vs_ref(l_max, n_syms):
         jnp.asarray(book.sorted_symbols, jnp.int32),
     )
     kw = dict(l_max=l_max, max_symlen=stream.max_symlen)
-    out_kernel = huffman_decode_padded(*args, **kw, block_words=128)
+    out_kernel = huffman_decode_padded(
+        *args, **kw, block_words=128, interpret=True
+    )
     out_ref = kref.huffman_decode_padded_ref(*args, **kw)
     np.testing.assert_array_equal(np.asarray(out_kernel), np.asarray(out_ref))
     # compacted stream equals original symbols
@@ -69,10 +71,10 @@ def test_idct_dequant_kernel_vs_ref(n, e, w):
     rng = np.random.default_rng(n * e + w)
     t = _quant_table(e)
     levels = rng.integers(0, 256, (w, e)).astype(np.int32)
-    basis = dctlib.idct_basis(n, e)
+    lut, _ = quant_grid(t)
     out_k = idct_dequant(
-        jnp.asarray(levels), t.zone, t.scale, basis, t.mu, t.alpha1,
-        n=n, block_windows=256,
+        jnp.asarray(levels), lut, recon_scale(t),
+        n=n, block_windows=256, interpret=True,
     )
     out_r = kref.idct_dequant_ref(jnp.asarray(levels), t, n=n)
     np.testing.assert_allclose(
@@ -88,8 +90,8 @@ def test_dct_quant_kernel_vs_ref(n, e, w):
     windows = rng.standard_normal((w, n)).astype(np.float32)
     basis = dctlib.dct_basis(n, e)
     out_k = dct_quant(
-        jnp.asarray(windows), t.zone, t.scale, basis, t.mu, t.alpha1,
-        e=e, block_windows=128,
+        jnp.asarray(windows), t, basis, e=e, block_windows=128,
+        interpret=True,
     )
     out_r = kref.dct_quant_ref(jnp.asarray(windows), t, e=e)
     k, r = np.asarray(out_k), np.asarray(out_r)
@@ -144,7 +146,7 @@ def test_huffman_decode_dense_fused_compaction(l_max, n_syms):
         jnp.asarray(book.rank_offset, jnp.int32),
         jnp.asarray(book.sorted_symbols, jnp.int32),
         l_max=l_max, max_symlen=stream.max_symlen,
-        num_symbols=n_syms, block_words=128,
+        num_symbols=n_syms, block_words=128, interpret=True,
     )
     np.testing.assert_array_equal(
         np.asarray(out).astype(np.uint8), syms
@@ -182,11 +184,11 @@ def test_decode_megakernel_bit_identical_to_xla_bucket():
 
     plan, hi, lo, sl, statics = _bucket_operands()
     ref = _decode_bucket(
-        hi, lo, sl, plan.tables, plan.lut, plan.basis,
+        hi, lo, sl, plan.tables, plan.lut, plan.rscale,
         use_kernels=False, **statics,
     )
     got = _decode_bucket(
-        hi, lo, sl, plan.tables, plan.lut, plan.basis,
+        hi, lo, sl, plan.tables, plan.lut, plan.rscale,
         use_kernels=True, **statics,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -233,7 +235,7 @@ def test_decode_bucket_kernel_path_is_one_pallas_call():
     plan, hi, lo, sl, statics = _bucket_operands()
     fused = jax.make_jaxpr(functools.partial(
         _decode_bucket_math, use_kernels=True, **statics
-    ))(hi, lo, sl, plan.tables, plan.lut, plan.basis)
+    ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale)
     assert _count_eqns(fused.jaxpr, "pallas_call") == 1
 
     w = int(hi.shape[0])
@@ -246,14 +248,15 @@ def test_decode_bucket_kernel_path_is_one_pallas_call():
 
     unfused = jax.make_jaxpr(functools.partial(
         _decode_bucket_math, use_kernels=False, **statics
-    ))(hi, lo, sl, plan.tables, plan.lut, plan.basis)
+    ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale)
     assert _count_eqns(unfused.jaxpr, "pallas_call") == 0
 
 
 def test_encode_fused_kernel_bit_identical_to_xla_bucket():
-    """The fused encode tile (DCT + quantize + one-hot codeword lookup +
-    chunk-parallel pack in one pallas_call) emits the exact chunk parts of
-    the XLA engine path, across chunk sizes including exact mode."""
+    """The kernel encode arm (DCT + quantize, then codeword lookup + greedy
+    pack + word materialization in one pallas_call) emits the exact chunk
+    parts of the XLA engine path, across chunk sizes including exact
+    mode."""
     from repro.core import DOMAIN_DEFAULTS, calibrate
     from repro.data import make_signal
     from repro.serving.batch_encode import (
@@ -286,8 +289,8 @@ def test_encode_fused_kernel_bit_identical_to_xla_bucket():
             n=n, e=e, chunk_size=chunk, check_gaps=False,
         )
         got = _encode_bucket_kernels(
-            jnp.asarray(x), jnp.asarray(counts), plan.tables, plan.basis,
-            n=n, e=e, chunk_size=chunk, check_gaps=False,
+            jnp.asarray(x), jnp.asarray(counts), plan.tables,
+            n=n, e=e, l_max=cfg.l_max, chunk_size=chunk, check_gaps=False,
         )
         for a, b in zip(ref, got):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -312,14 +315,15 @@ def test_encode_kernel_path_is_one_pallas_call():
     counts = jnp.zeros((2,), jnp.int32)
     traced = jax.make_jaxpr(functools.partial(
         _encode_bucket_kernels_math,
-        n=cfg.n, e=cfg.e, chunk_size=64, check_gaps=True,
-    ))(x, counts, plan.tables, plan.basis)
+        n=cfg.n, e=cfg.e, l_max=cfg.l_max, chunk_size=64, check_gaps=True,
+    ))(x, counts, plan.tables)
     assert _count_eqns(traced.jaxpr, "pallas_call") == 1
 
 
 def test_dct_quant_exact_arm_matches_reference():
-    """dct_quant(exact=True) traces the reference quantizer inside the
-    tile: levels equal the XLA forward_dct+quantize bit for bit."""
+    """dct_quant(exact=True) traces the reference split DCT and quantizer
+    inside the tile: levels equal the XLA forward_dct+quantize bit for
+    bit."""
     from repro.core.quantize import quantize
 
     rng = np.random.default_rng(17)
@@ -328,8 +332,8 @@ def test_dct_quant_exact_arm_matches_reference():
     windows = rng.standard_normal((w, n)).astype(np.float32)
     basis = dctlib.dct_basis(n, e)
     out = dct_quant(
-        jnp.asarray(windows), t.zone, t.scale, basis, t.mu, t.alpha1,
-        e=e, block_windows=128, exact=True,
+        jnp.asarray(windows), t, basis, e=e, block_windows=128,
+        interpret=True, exact=True,
     )
     ref = jax.jit(
         lambda win: quantize(dctlib.forward_dct(win, e), t).astype(jnp.int32)
@@ -369,6 +373,8 @@ def test_i32_offset_guard_at_2gb_boundary():
             scale=spec((16,), jnp.float32),
             mu=spec((), jnp.float32),
             alpha1=spec((), jnp.float32),
+            thresholds=spec((2, 16, 128), jnp.float32),
+            grid=spec((16, 256), jnp.float32),
         ),
     )
     with pytest.raises(ValueError, match="int32 offset range"):

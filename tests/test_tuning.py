@@ -22,7 +22,7 @@ from repro.tuning.autotune import (
     tune,
     tuned_blocks,
 )
-from repro.tuning.cost_model import CostModel, default_cost_model
+from repro.tuning.cost_model import PEAKS, CostModel, default_cost_model
 from repro.tuning.policy import (
     BucketPolicy,
     COST_BALANCED,
@@ -252,8 +252,8 @@ def test_block_candidates_clip_and_dedupe():
         assert c["block_words"] <= 100 and c["block_windows"] <= 50
     small = decode_block_candidates(1, 1)
     assert small == [{"block_words": 1, "block_windows": 1}]
-    assert encode_block_candidates(3) == [
-        {"block_rows": 1}, {"block_rows": 2}, {"block_rows": 3}
+    assert encode_block_candidates(300) == [
+        {"block_lanes": 128}, {"block_lanes": 256}, {"block_lanes": 300}
     ]
 
 
@@ -324,7 +324,7 @@ def test_cost_balanced_ladder_from_model():
 # Cost model.
 # ---------------------------------------------------------------------------
 def test_cost_model_monotone_in_shape():
-    cm = CostModel(backend="cpu")
+    cm = CostModel(device_kind="cpu")
     base = cm.decode_bucket_cost(1024, 256, e=6, n=32)
     assert cm.decode_bucket_cost(2048, 256, e=6, n=32) > base
     assert cm.decode_bucket_cost(1024, 512, e=6, n=32) > base
@@ -335,7 +335,7 @@ def test_cost_model_monotone_in_shape():
 
 
 def test_cost_model_seed_rescales():
-    cm = CostModel(backend="cpu")
+    cm = CostModel(device_kind="cpu")
     raw = cm.signal_decode_cost(100, 50, e=6, n=32)
     cm.seed(
         "decode",
@@ -349,7 +349,7 @@ def test_cost_model_seed_rescales():
 
 
 def test_cost_model_observe_calibrates():
-    cm = CostModel(backend="cpu")
+    cm = CostModel(device_kind="cpu")
     t = cm.decode_bucket_cost(1024, 256, e=6, n=32)
     cm.observe("decode", predicted_s=1.0, measured_s=3.0)
     assert cm.calibration("decode") == pytest.approx(3.0)
@@ -361,7 +361,7 @@ def test_cost_model_observe_calibrates():
 
 
 def test_edges_per_octave_bounded():
-    for backend in ("cpu", "gpu", "tpu"):
-        d = CostModel(backend=backend).edges_per_octave()
+    for kind in PEAKS:
+        d = CostModel(device_kind=kind).edges_per_octave()
         assert 1 <= d <= 4
     assert default_cost_model() is default_cost_model()
