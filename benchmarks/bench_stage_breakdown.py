@@ -148,7 +148,7 @@ def run_kernels(fast: bool = True, out_path: str = None) -> dict:
     equations per bucket, device programs per bucket) read off the jaxprs.
     """
     import repro.kernels.ops as kops
-    from repro.serving.batch_decode import _decode_bucket, _decode_bucket_math
+    from repro.serving.batch_decode import _decode_bucket, _decode_bucket_phases
     from repro.serving.batch_encode import (
         _build_encode_plan,
         _encode_bucket,
@@ -198,10 +198,10 @@ def run_kernels(fast: bool = True, out_path: str = None) -> dict:
         t_staged_k, _ = _time(staged_kernels, hi, lo, sl)
 
         fused_jaxpr = jax.make_jaxpr(functools.partial(
-            _decode_bucket_math, use_kernels=True, **statics
+            _decode_bucket_phases, use_kernels=True, **statics
         ))(*args)
         unfused_jaxpr = jax.make_jaxpr(functools.partial(
-            _decode_bucket_math, use_kernels=False, **statics
+            _decode_bucket_phases, use_kernels=False, **statics
         ))(*args)
 
         # encode side: one single-signal bucket through both arms

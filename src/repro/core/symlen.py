@@ -672,15 +672,16 @@ def compact_padded_scatter(
     positions beyond the last real symbol stay zero.
     """
     w, max_symlen = padded.shape
-    symlen = symlen.astype(jnp.int32)
-    offsets = jnp.cumsum(symlen) - symlen  # exclusive prefix sum, int32[W]
-    slot = jnp.arange(max_symlen, dtype=jnp.int32)
-    idx = offsets[:, None] + slot[None, :]  # [W, max_symlen]
-    valid = slot[None, :] < symlen[:, None]
-    # invalid lanes scatter out of bounds and are dropped
-    idx = jnp.where(valid, idx, num_symbols)
-    out = jnp.zeros((num_symbols,), dtype=padded.dtype)
-    return out.at[idx.ravel()].set(padded.ravel(), mode="drop")
+    with jax.named_scope("fptc.decode.compact"):
+        symlen = symlen.astype(jnp.int32)
+        offsets = jnp.cumsum(symlen) - symlen  # exclusive prefix sum
+        slot = jnp.arange(max_symlen, dtype=jnp.int32)
+        idx = offsets[:, None] + slot[None, :]  # [W, max_symlen]
+        valid = slot[None, :] < symlen[:, None]
+        # invalid lanes scatter out of bounds and are dropped
+        idx = jnp.where(valid, idx, num_symbols)
+        out = jnp.zeros((num_symbols,), dtype=padded.dtype)
+        return out.at[idx.ravel()].set(padded.ravel(), mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -731,7 +732,8 @@ def unpack_symlen(
         new_lo = _shl32(cur_lo, length)
         return (new_hi, new_lo), sym
 
-    (_, _), padded = jax.lax.scan(
-        slot_step, (hi, lo), None, length=max_symlen
-    )  # padded: uint8[max_symlen, W]
+    with jax.named_scope("fptc.decode.huffman"):
+        (_, _), padded = jax.lax.scan(
+            slot_step, (hi, lo), None, length=max_symlen
+        )  # padded: uint8[max_symlen, W]
     return compact_padded_scatter(padded.T, symlen, num_symbols)

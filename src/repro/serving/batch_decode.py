@@ -86,6 +86,7 @@ from repro.serving.engine import (
     member_positions,
     p2,
     putter,
+    span,
     symlen_bucket,
 )
 from repro.tuning import autotune as _autotune
@@ -163,7 +164,7 @@ def _build_decode_plan(tables: DomainTables, key, device) -> DecodePlan:
 # ---------------------------------------------------------------------------
 # The fused bucket decode — ONE jit specialization per bucket shape.
 # ---------------------------------------------------------------------------
-def _decode_bucket_math(
+def _decode_bucket_phases(
     hi: jnp.ndarray,  # uint32[Wp]   (concatenated + zero-padded words)
     lo: jnp.ndarray,  # uint32[Wp]
     sl: jnp.ndarray,  # int32[Wp]    (0 on padding words)
@@ -197,6 +198,13 @@ def _decode_bucket_math(
     ``pallas_call`` (the decode megakernel, ``kernels/decode_fused.py``) —
     no intermediate ``[max_symlen, W]`` tile, no separate compaction or
     iDCT program.
+
+    The XLA arm's phases carry named scopes (``fptc.decode.huffman``,
+    ``fptc.decode.compact``, ``fptc.decode.idct``), which reach the
+    compiled ops' ``op_name`` metadata and so a profiler trace.  Scopes
+    are debug info, which JAX leaves out of the persistent compile cache's
+    key; the program's name is in the key, and names the phases, so an
+    executable compiled before the scopes existed is never loaded here.
 
     ``tuning_epoch`` is a pure retrace key: the kernel path resolves its
     Pallas block sizes from the tuning cache *at trace time*
@@ -245,8 +253,9 @@ def _decode_bucket_math(
         levels = unpredict_levels(
             grid.astype(jnp.uint32), seg, pred_id, bands
         ).astype(jnp.int32)
-    coeffs = lut[jnp.arange(e, dtype=jnp.int32)[None, :], levels]
-    return dct.inverse_dct(coeffs, n, scale=rscale)
+    with jax.named_scope("fptc.decode.idct"):
+        coeffs = lut[jnp.arange(e, dtype=jnp.int32)[None, :], levels]
+        return dct.inverse_dct(coeffs, n, scale=rscale)
 
 
 _decode_bucket = functools.partial(
@@ -255,7 +264,7 @@ _decode_bucket = functools.partial(
         "l_max", "max_symlen", "num_windows", "n", "e", "use_kernels",
         "coding", "tuning_epoch",
     ),
-)(_decode_bucket_math)
+)(_decode_bucket_phases)
 
 
 def bucket_cache_size() -> Optional[int]:
@@ -379,14 +388,17 @@ class DecodedBatch:
         copies in flight before the first materializes.  Quarantined
         positions hold their typed per-request error instead of samples —
         a poisoned signal never raises batch-wide here."""
-        host = fetch_to_host(self._groups)
+        with span("fptc.drain.d2h",
+                  bytes=lambda: sum(g.nbytes for g in self._groups)):
+            host = fetch_to_host(self._groups)
         out: List[Any] = []
-        for i, s in enumerate(self._slices):
-            if s is None:
-                out.append(self._poisoned[i])
-                continue
-            rows = host[s.group][s.win_off:s.win_off + s.num_windows]
-            out.append(rows.reshape(-1)[: s.signal_length].copy())
+        with span("fptc.drain.stitch"):
+            for i, s in enumerate(self._slices):
+                if s is None:
+                    out.append(self._poisoned[i])
+                    continue
+                rows = host[s.group][s.win_off:s.win_off + s.num_windows]
+                out.append(rows.reshape(-1)[: s.signal_length].copy())
         return out
 
 
@@ -738,32 +750,33 @@ class BatchDecoder:
                     "single DomainTables"
                 )
 
-        # with several shards, split each group at cost-balanced (not
-        # equal-count) boundaries over the model's per-container decode
-        # cost — container metadata carries everything the model needs
-        item_costs = None
-        if self.scheduler.num_shards > 1:
-            item_costs = [
-                self.cost_model.signal_decode_cost(
-                    c.num_words, c.num_windows,
-                    e=c.e, n=c.n, max_symlen=symlen_bucket(c.max_symlen),
-                )
-                for c in containers
-            ]
-        buckets = self.scheduler.buckets(
-            [c.plan_key for c in containers], item_costs=item_costs
-        )
-        member_pos = member_positions(buckets, len(containers))
-        # staging stays lazy: the executor's worker runs the host concat +
-        # h2d upload of bucket k+1 while bucket k's decode dispatches
-        lazy = [
-            functools.partial(
-                _stage_container_group,
-                [containers[i] for i in b.items], b.key, b.device, b.shard,
-                self.scheduler.round,
+        with span("fptc.schedule"):
+            # with several shards, split each group at cost-balanced (not
+            # equal-count) boundaries over the model's per-container decode
+            # cost — container metadata carries everything the model needs
+            item_costs = None
+            if self.scheduler.num_shards > 1:
+                item_costs = [
+                    self.cost_model.signal_decode_cost(
+                        c.num_words, c.num_windows,
+                        e=c.e, n=c.n, max_symlen=symlen_bucket(c.max_symlen),
+                    )
+                    for c in containers
+                ]
+            buckets = self.scheduler.buckets(
+                [c.plan_key for c in containers], item_costs=item_costs
             )
-            for b in buckets
-        ]
+            member_pos = member_positions(buckets, len(containers))
+            # staging stays lazy: the executor's worker runs the host concat +
+            # h2d upload of bucket k+1 while bucket k's decode dispatches
+            lazy = [
+                functools.partial(
+                    _stage_container_group,
+                    [containers[i] for i in b.items], b.key, b.device, b.shard,
+                    self.scheduler.round,
+                )
+                for b in buckets
+            ]
         batch = self.decode_streams(lazy, tables)
         # decode_streams orders slices by (group, member); restore the
         # caller's container order

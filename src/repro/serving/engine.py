@@ -24,6 +24,8 @@ module owns that machinery so the engines are thin *stage definitions*:
     signal matrix materializes *inside* the bucket's fused dispatch as a
     batched ``dynamic_slice`` gather out of decoded window tensors
     (optionally donating the source buffer on its last use).
+  * :func:`span` — the serving path's host spans (``fptc.*``), profiler
+    events that exist only while ``jax.profiler`` is tracing.
 
 Pipelining and sharding change *when* and *where* buckets run — never what
 bytes they produce: bucket padding is invisible to decoded samples and
@@ -32,6 +34,7 @@ single-device path is the degenerate case (one shard, no prefetch).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -52,6 +55,7 @@ from typing import (
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.tuning.policy import BucketPolicy, PolicyArg
 
@@ -66,6 +70,7 @@ __all__ = [
     "BucketScheduler",
     "PipelineExecutor",
     "ExecutorStats",
+    "span",
     "GatherStage",
     "SubmitBuffer",
     "fetch_to_host",
@@ -412,13 +417,32 @@ class SubmitBuffer:
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class ExecutorStats:
-    runs: int = 0
+    """Cumulative counters of one executor; callers read them as deltas."""
+
     buckets: int = 0
     pipelined_buckets: int = 0  # buckets whose upload ran on the worker
     upload_s: float = 0.0  # host staging + h2d time (worker or inline)
-    dispatch_s: float = 0.0  # main-thread dispatch time (async: excludes
-    # device compute that overlaps later stages)
-    max_inflight: int = 0  # peak buckets simultaneously staged/dispatching
+
+
+_IDLE_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **stats: Any):
+    """A host span of the serving path, live only while ``jax.profiler``
+    is tracing.
+
+    It opens a ``TraceAnnotation`` called ``name`` (``fptc.<part>``): an
+    event on the trace's host plane, on the same clock as the device ops,
+    so idle device time can be put down to the host work open at that
+    moment.  A stat value may be a zero-argument callable, evaluated only
+    while tracing.  With the profiler off the cost is one
+    ``is_enabled()`` check: no annotation is made and no stat computed.
+    Counters live in the ``*Stats`` dataclasses; no span is kept in memory.
+    """
+    if not TraceAnnotation.is_enabled():
+        return _IDLE_SPAN
+    return TraceAnnotation(
+        name, **{k: v() if callable(v) else v for k, v in stats.items()})
 
 
 class PipelineExecutor:
@@ -449,20 +473,6 @@ class PipelineExecutor:
         self.stats = ExecutorStats()
         self._pool: Optional[ThreadPoolExecutor] = None
         self._lock = threading.Lock()
-        self._inflight = 0
-
-    @property
-    def inflight(self) -> int:
-        """Buckets currently staged or dispatching (in-flight accounting
-        for the serving front-end's load reporting; 0 between runs)."""
-        with self._lock:
-            return self._inflight
-
-    def _inflight_add(self, delta: int) -> None:
-        with self._lock:
-            self._inflight += delta
-            if self._inflight > self.stats.max_inflight:
-                self.stats.max_inflight = self._inflight
 
     def _worker(self) -> ThreadPoolExecutor:
         with self._lock:
@@ -479,7 +489,6 @@ class PipelineExecutor:
         dispatch: Callable[[Any, Any], Any],
     ) -> List[Any]:
         n = len(work)
-        self.stats.runs += 1
         self.stats.buckets += n
         if n == 0:
             return []
@@ -487,29 +496,13 @@ class PipelineExecutor:
         def timed_upload(b: Any) -> Any:
             t0 = time.perf_counter()
             try:
-                return upload(b)
+                with span("fptc.stage"):
+                    return upload(b)
             finally:
                 self.stats.upload_s += time.perf_counter() - t0
 
-        def timed_dispatch(b: Any, staged: Any) -> Any:
-            t0 = time.perf_counter()
-            try:
-                return dispatch(b, staged)
-            finally:
-                self.stats.dispatch_s += time.perf_counter() - t0
-                self._inflight_add(-1)
-
         if not self.pipeline or n == 1:
-            out = []
-            for b in work:
-                self._inflight_add(1)
-                try:
-                    staged = timed_upload(b)
-                except BaseException:
-                    self._inflight_add(-1)
-                    raise
-                out.append(timed_dispatch(b, staged))
-            return out
+            return [dispatch(b, timed_upload(b)) for b in work]
 
         pool = self._worker()
         results: List[Any] = [None] * n
@@ -517,16 +510,10 @@ class PipelineExecutor:
 
         def pop_dispatch() -> None:
             j, bj, fut = pending.popleft()
-            try:
-                staged = fut.result()
-            except BaseException:
-                self._inflight_add(-1)
-                raise
-            results[j] = timed_dispatch(bj, staged)
+            results[j] = dispatch(bj, fut.result())
 
         try:
             for i, b in enumerate(work):
-                self._inflight_add(1)
                 pending.append((i, b, pool.submit(timed_upload, b)))
                 self.stats.pipelined_buckets += 1
                 if len(pending) > self.prefetch:
@@ -547,7 +534,6 @@ class PipelineExecutor:
                         fut.result()
                     except BaseException:
                         pass  # the primary exception is already in flight
-                self._inflight_add(-1)
         return results
 
 

@@ -182,17 +182,17 @@ def test_v3_decode_bucket_is_one_pallas_call(power_tables, coding):
     import functools
 
     from test_kernels import _count_eqns
-    from repro.serving.batch_decode import _decode_bucket_math
+    from repro.serving.batch_decode import _decode_bucket_phases
 
     t3 = _retable(power_tables, **coding)
     plan, hi, lo, sl, v3, statics = _v3_bucket_operands(t3)
     fused = jax.make_jaxpr(functools.partial(
-        _decode_bucket_math, use_kernels=True, **statics
+        _decode_bucket_phases, use_kernels=True, **statics
     ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3)
     assert _count_eqns(fused.jaxpr, "pallas_call") == 1
 
     unfused = jax.make_jaxpr(functools.partial(
-        _decode_bucket_math, use_kernels=False, **statics
+        _decode_bucket_phases, use_kernels=False, **statics
     ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale, v3)
     assert _count_eqns(unfused.jaxpr, "pallas_call") == 0
 

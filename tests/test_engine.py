@@ -235,12 +235,15 @@ def test_executor_teardown_joins_inflight_upload():
     ex = PipelineExecutor(pipeline=True, prefetch=2)
     upload_started = threading.Event()
     uploads_done = []
+    running = []  # uploads entered and not yet returned
 
     def upload(b):
+        running.append(b.key)
         if b.key == 1:
             upload_started.set()
             time.sleep(0.3)  # long enough to be RUNNING at teardown
         uploads_done.append(b.key)
+        running.remove(b.key)
         return b.key
 
     def dispatch(b, staged):
@@ -253,10 +256,10 @@ def test_executor_teardown_joins_inflight_upload():
         ex.run(_work(4), upload, dispatch)
     # the in-flight upload was joined (completed), not abandoned
     assert 1 in uploads_done
-    # the inflight gauge unwound: nothing leaked into the next run
-    assert ex.inflight == 0
-    assert ex.run(_work(2), lambda b: b.key, lambda b, s: s) == [0, 1]
-    assert ex.inflight == 0
+    # no upload is still running: nothing leaked into the next run
+    assert running == []
+    assert ex.run(_work(2), upload, lambda b, s: s) == [0, 1]
+    assert running == []
 
 
 def test_executor_rejects_bad_prefetch():

@@ -230,11 +230,11 @@ def test_decode_bucket_kernel_path_is_one_pallas_call():
     pallas_call, and no jaxpr intermediate carries the ``[max_symlen, W]``
     padded-tile shape (the HBM round trip the fusion removes).  The XLA
     arm of the same bucket is pallas-free."""
-    from repro.serving.batch_decode import _decode_bucket_math
+    from repro.serving.batch_decode import _decode_bucket_phases
 
     plan, hi, lo, sl, statics = _bucket_operands()
     fused = jax.make_jaxpr(functools.partial(
-        _decode_bucket_math, use_kernels=True, **statics
+        _decode_bucket_phases, use_kernels=True, **statics
     ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale)
     assert _count_eqns(fused.jaxpr, "pallas_call") == 1
 
@@ -247,7 +247,7 @@ def test_decode_bucket_kernel_path_is_one_pallas_call():
     )
 
     unfused = jax.make_jaxpr(functools.partial(
-        _decode_bucket_math, use_kernels=False, **statics
+        _decode_bucket_phases, use_kernels=False, **statics
     ))(hi, lo, sl, plan.tables, plan.lut, plan.rscale)
     assert _count_eqns(unfused.jaxpr, "pallas_call") == 0
 
