@@ -35,6 +35,10 @@ typed error and never a silent switch to another path:
   * the container-v3 decode epilogue (gathers + a prefix sum) has no Mosaic
     lowering, so v3 buckets are refused on the chip.
 
+:func:`decode_kernel_fits` asks the same questions of a decode bucket
+without raising: the decode engine's per-bucket arm choice uses it to keep
+such buckets on the XLA arm.
+
 The megakernel wrappers resolve their Pallas block sizes at TRACE time:
 ``block_*=None`` (the engines' calling convention) consults the
 :mod:`repro.tuning.autotune` cache for this (backend, plan key, bucket
@@ -69,6 +73,7 @@ __all__ = [
     "check_decode_vmem",
     "check_encode_vmem",
     "decode_vmem_bytes",
+    "decode_kernel_fits",
     "KernelLimitError",
     "VMEM_BUDGET_BYTES",
     "VMEM_LIMIT_BYTES",
@@ -168,6 +173,49 @@ def check_decode_vmem(num_windows: int, **kw) -> None:
         )
 
 
+def _decode_blocks(
+    num_words: int, num_windows: int, *, n: int, e: int, l_max: int,
+    max_symlen: int, coding=_TRIVIAL_CODING,
+) -> tuple:
+    """``(block_words, block_windows)`` the decode megakernel runs a bucket
+    with: the tuning cache's winner for this (backend, plan key, bucket
+    shape), else the kernel's built-in defaults."""
+    tuned = _tuned_blocks(
+        "decode",
+        plan_key=(n, e, l_max, max_symlen) + _coding_key(coding),
+        shape=(int(num_words), int(num_windows)),
+    )
+    return (
+        int(tuned.get("block_words", _hd.BLOCK_WORDS)),
+        int(tuned.get("block_windows", _df.BLOCK_WINDOWS)),
+    )
+
+
+def decode_kernel_fits(
+    num_words: int, num_windows: int, *, n: int, e: int, l_max: int,
+    max_symlen: int, coding=_TRIVIAL_CODING,
+) -> bool:
+    """Whether the compiled decode megakernel takes a bucket: the checks
+    :func:`decode_bucket_fused` makes on the chip (the coding, the int32
+    offsets, the VMEM budget with the blocks it would run), answered
+    before dispatch and without raising."""
+    if tuple(coding) != _TRIVIAL_CODING:
+        return False
+    block_words, block_windows = _decode_blocks(
+        num_words, num_windows, n=n, e=e, l_max=l_max,
+        max_symlen=max_symlen, coding=coding,
+    )
+    try:
+        check_i32_offsets(num_windows * e, max_symlen)
+        check_decode_vmem(
+            num_windows, n=n, e=e, max_symlen=max_symlen,
+            block_words=block_words, block_windows=block_windows,
+        )
+    except KernelLimitError:
+        return False
+    return True
+
+
 def check_encode_vmem(chunk_size: int, word_slots: int, lanes: int) -> None:
     """Refuse an encode bucket whose pack-kernel step exceeds the limit
     (exact-mode encodes make the whole signal one chunk)."""
@@ -260,15 +308,14 @@ def decode_bucket_fused(
             "buckets with use_kernels=False"
         )
     if block_words is None or block_windows is None:
-        tuned = _tuned_blocks(
-            "decode",
-            plan_key=(n, e, l_max, max_symlen) + _coding_key(coding),
-            shape=(int(hi.shape[0]), int(num_windows)),
+        tuned_words, tuned_windows = _decode_blocks(
+            hi.shape[0], num_windows, n=n, e=e, l_max=l_max,
+            max_symlen=max_symlen, coding=coding,
         )
         if block_words is None:
-            block_words = tuned.get("block_words", _hd.BLOCK_WORDS)
+            block_words = tuned_words
         if block_windows is None:
-            block_windows = tuned.get("block_windows", _df.BLOCK_WINDOWS)
+            block_windows = tuned_windows
     if not interpret:
         check_decode_vmem(
             num_windows, n=n, e=e, max_symlen=max_symlen,

@@ -199,12 +199,14 @@ def _decode_bucket_phases(
     no intermediate ``[max_symlen, W]`` tile, no separate compaction or
     iDCT program.
 
-    The XLA arm's phases carry named scopes (``fptc.decode.huffman``,
-    ``fptc.decode.compact``, ``fptc.decode.idct``), which reach the
-    compiled ops' ``op_name`` metadata and so a profiler trace.  Scopes
-    are debug info, which JAX leaves out of the persistent compile cache's
-    key; the program's name is in the key, and names the phases, so an
-    executable compiled before the scopes existed is never loaded here.
+    Each arm carries named scopes, which reach the compiled ops'
+    ``op_name`` metadata and so a profiler trace: the kernel arm one,
+    ``fptc.decode.fused`` (the ``pallas_call`` and the operand layout
+    around it), the XLA arm one per phase (``fptc.decode.huffman``,
+    ``fptc.decode.compact``, ``fptc.decode.idct``).  Scopes are debug
+    info, which JAX leaves out of the persistent compile cache's key; the
+    program's name is in the key, and names the phases, so an executable
+    compiled before the XLA arm's scopes existed is never loaded here.
 
     ``tuning_epoch`` is a pure retrace key: the kernel path resolves its
     Pallas block sizes from the tuning cache *at trace time*
@@ -234,11 +236,12 @@ def _decode_bucket_phases(
     if use_kernels:
         from repro.kernels import ops as kops
 
-        return kops.decode_bucket_fused(
-            hi, lo, sl, tables, lut, rscale, v3,
-            l_max=l_max, max_symlen=max_symlen, num_windows=num_windows,
-            n=n, e=e, coding=coding,
-        )
+        with jax.named_scope("fptc.decode.fused"):
+            return kops.decode_bucket_fused(
+                hi, lo, sl, tables, lut, rscale, v3,
+                l_max=l_max, max_symlen=max_symlen, num_windows=num_windows,
+                n=n, e=e, coding=coding,
+            )
     syms = symlen.unpack_symlen(
         hi, lo, sl,
         tables.dec_limit, tables.dec_first, tables.dec_rank, tables.dec_syms,
@@ -265,6 +268,26 @@ _decode_bucket = functools.partial(
         "coding", "tuning_epoch",
     ),
 )(_decode_bucket_phases)
+
+
+def _kernel_arm(plan: DecodePlan, words: int, num_windows: int,
+                max_symlen: int) -> bool:
+    """The arm of one bucket when the engine was left to choose
+    (``use_kernels=None``): the Pallas megakernel where it runs compiled
+    (a TPU backend) and takes the bucket, else the XLA arm.  Both give the
+    same bits; a bucket the kernel would refuse never reaches it.  There is
+    no lower size bound: on a TPU v5e the kernel arm took 5-14x less device
+    time than the XLA arm at every bucket size measured, from one 5,000-
+    sample strip (256 padded words) to 2 Mi padded words (PERF.md)."""
+    from repro.kernels import ops as kops
+
+    return (
+        kops.on_tpu()
+        and kops.decode_kernel_fits(
+            words, num_windows, n=plan.n, e=plan.e, l_max=plan.l_max,
+            max_symlen=max_symlen, coding=plan.coding,
+        )
+    )
 
 
 def bucket_cache_size() -> Optional[int]:
@@ -542,6 +565,7 @@ class BatchDecoderStats:
     batches: int = 0
     containers: int = 0
     dispatches: int = 0  # fused bucket launches
+    kernel_dispatches: int = 0  # of which on the Pallas megakernel arm
     plan_hits: int = 0
     plan_misses: int = 0
     quarantined: int = 0  # signals poisoned out of quarantine=True batches
@@ -575,6 +599,13 @@ class BatchDecoder:
     device), with the per-device split cost-balanced over
     ``cost_model``'s per-container decode-cost prediction — policy,
     pipelining and sharding all change scheduling only, never bytes.
+
+    ``use_kernels`` picks the program that decodes a bucket: ``True`` the
+    Pallas megakernel, ``False`` the XLA arm, ``None`` (the default) a
+    choice per bucket — the megakernel on a TPU where it takes the bucket
+    (trivial coding, within the VMEM budget), else the XLA arm; the arms
+    give the same bits.  ``FPTC_USE_KERNELS=1`` turns ``None`` into
+    ``True``.  ``stats.bucket_pad`` records each dispatch's ``arm``.
     """
 
     def __init__(
@@ -588,10 +619,11 @@ class BatchDecoder:
         policy: PolicyArg = None,
         cost_model: Optional[CostModel] = None,
     ):
-        # None defers to the process-wide FPTC_USE_KERNELS default — the
-        # kernels-interpret CI leg flips every engine onto the fused path
-        if use_kernels is None:
-            use_kernels = default_use_kernels()
+        # FPTC_USE_KERNELS=1 forces the kernel arm on engines left at None
+        # (the kernels-interpret CI leg flips every engine onto the fused
+        # path); otherwise None picks the arm per bucket (_kernel_arm)
+        if use_kernels is None and default_use_kernels():
+            use_kernels = True
         self.use_kernels = use_kernels
         self._plans = PlanCache(_build_decode_plan, plan_cache_size)
         self.scheduler = BucketScheduler(devices=devices, policy=policy)
@@ -833,6 +865,10 @@ class BatchDecoder:
             )
             wp = int(grp.hi.shape[0])
             num_windows = self.scheduler.round(max(grp.total_windows, 1))
+            max_symlen = symlen_bucket(grp.max_symlen)
+            use_kernels = self.use_kernels
+            if use_kernels is None:
+                use_kernels = _kernel_arm(plan, wp, num_windows, max_symlen)
             if plan.coding != TRIVIAL_CODING:
                 if grp.v3_idx is None or grp.v3_seg is None:
                     raise ValueError(
@@ -853,23 +889,23 @@ class BatchDecoder:
                 plan.rscale,
                 v3,
                 l_max=plan.l_max,
-                max_symlen=symlen_bucket(grp.max_symlen),
+                max_symlen=max_symlen,
                 num_windows=num_windows,
                 n=plan.n,
                 e=plan.e,
-                use_kernels=self.use_kernels,
+                use_kernels=use_kernels,
                 coding=plan.coding,
                 # retrace when the tuning cache learns better block sizes
                 # (kernel path only — the XLA arm has no tunables)
-                tuning_epoch=(
-                    _autotune.epoch() if self.use_kernels else 0
-                ),
+                tuning_epoch=_autotune.epoch() if use_kernels else 0,
             )
             self.stats.dispatches += 1
+            self.stats.kernel_dispatches += int(use_kernels)
             self.stats.bucket_pad.append({
                 "plan_key": tuple(grp.plan_key),
                 "shard": grp.shard,
                 "policy": self.scheduler.policy.name,
+                "arm": "pallas" if use_kernels else "xla",
                 "words": grp.live_words,
                 "words_padded": wp,
                 "windows": grp.total_windows,
@@ -907,12 +943,14 @@ class BatchDecoder:
 # ---------------------------------------------------------------------------
 # Process-wide default decoders (codec.decode_device rides these).
 # ---------------------------------------------------------------------------
-_DEFAULTS: Dict[bool, BatchDecoder] = {}
+_DEFAULTS: Dict[Optional[bool], BatchDecoder] = {}
 
 
 def default_decoder(use_kernels: Optional[bool] = None) -> BatchDecoder:
-    if use_kernels is None:
-        use_kernels = default_use_kernels()
+    """Shared decoder per ``use_kernels`` as :class:`BatchDecoder` resolves
+    it (``None``: the arm per bucket, unless FPTC_USE_KERNELS forces it)."""
+    if use_kernels is None and default_use_kernels():
+        use_kernels = True
     dec = _DEFAULTS.get(use_kernels)
     if dec is None:
         dec = _DEFAULTS[use_kernels] = BatchDecoder(use_kernels=use_kernels)

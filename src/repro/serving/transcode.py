@@ -144,8 +144,9 @@ class Transcoder:
         policy: PolicyArg = None,
     ):
         # use_kernels threads through BOTH stage definitions: the decode
-        # megakernel and the fused encode tile (None = FPTC_USE_KERNELS
-        # env default; bytes are identical either way)
+        # megakernel and the fused encode tile (None: the decoder picks its
+        # arm per bucket, the encoder takes the FPTC_USE_KERNELS default;
+        # bytes are identical either way)
         self.decoder = decoder or BatchDecoder(
             use_kernels=use_kernels, pipeline=pipeline, devices=devices,
             prefetch=prefetch, policy=policy,
