@@ -605,7 +605,9 @@ class BatchDecoder:
     choice per bucket — the megakernel on a TPU where it takes the bucket
     (trivial coding, within the VMEM budget), else the XLA arm; the arms
     give the same bits.  ``FPTC_USE_KERNELS=1`` turns ``None`` into
-    ``True``.  ``stats.bucket_pad`` records each dispatch's ``arm``.
+    ``True``.  ``stats.bucket_pad`` records each dispatch's ``arm`` and
+    the ``device`` it ran on; while the profiler traces, each dispatch is
+    also an ``fptc.dispatch`` host span.
     """
 
     def __init__(
@@ -880,32 +882,39 @@ class BatchDecoder:
                 v3 = (grp.v3_idx, grp.v3_seg)
             else:
                 v3 = None
-            windows = _decode_bucket(
-                grp.hi,
-                grp.lo,
-                grp.symlen,
-                plan.tables,
-                plan.lut,
-                plan.rscale,
-                v3,
-                l_max=plan.l_max,
-                max_symlen=max_symlen,
-                num_windows=num_windows,
-                n=plan.n,
-                e=plan.e,
-                use_kernels=use_kernels,
-                coding=plan.coding,
-                # retrace when the tuning cache learns better block sizes
-                # (kernel path only — the XLA arm has no tunables)
-                tuning_epoch=_autotune.epoch() if use_kernels else 0,
-            )
+            # the jax device id the program is enqueued on; 0 stands for
+            # the default placement of the single-shard path
+            device = 0 if grp.device is None else grp.device.id
+            arm = "pallas" if use_kernels else "xla"
+            with span("fptc.dispatch", shard=grp.shard, device=device,
+                      arm=arm, words_padded=wp):
+                windows = _decode_bucket(
+                    grp.hi,
+                    grp.lo,
+                    grp.symlen,
+                    plan.tables,
+                    plan.lut,
+                    plan.rscale,
+                    v3,
+                    l_max=plan.l_max,
+                    max_symlen=max_symlen,
+                    num_windows=num_windows,
+                    n=plan.n,
+                    e=plan.e,
+                    use_kernels=use_kernels,
+                    coding=plan.coding,
+                    # retrace when the tuning cache learns better block
+                    # sizes (kernel path only — the XLA arm has no tunables)
+                    tuning_epoch=_autotune.epoch() if use_kernels else 0,
+                )
             self.stats.dispatches += 1
             self.stats.kernel_dispatches += int(use_kernels)
             self.stats.bucket_pad.append({
                 "plan_key": tuple(grp.plan_key),
                 "shard": grp.shard,
+                "device": device,
                 "policy": self.scheduler.policy.name,
-                "arm": "pallas" if use_kernels else "xla",
+                "arm": arm,
                 "words": grp.live_words,
                 "words_padded": wp,
                 "windows": grp.total_windows,

@@ -17,8 +17,8 @@ from repro.serving.engine import span, symlen_bucket
 
 from _synth import uniform_code_container
 
-HOST_SPANS = ("fptc.schedule", "fptc.stage", "fptc.drain.d2h",
-              "fptc.drain.stitch")
+HOST_SPANS = ("fptc.schedule", "fptc.stage", "fptc.dispatch",
+              "fptc.drain.d2h", "fptc.drain.stitch")
 DEVICE_SCOPES = ("fptc.decode.huffman", "fptc.decode.compact",
                  "fptc.decode.idct")
 
@@ -105,6 +105,11 @@ def test_decode_to_host_spans_in_a_cpu_trace(tmp_path):
     d2h = [st for n, st in events if n == "fptc.drain.d2h"]
     assert d2h == [{"bytes": sum(int(g.nbytes)
                                  for g in batch.device_windows)}]
+    dispatch = [st for n, st in events if n == "fptc.dispatch"]
+    pads = list(dec.stats.bucket_pad)[-len(dispatch):]
+    assert [(st["shard"], st["device"], st["words_padded"])
+            for st in dispatch] == [(p["shard"], p["device"], p["words_padded"])
+                                    for p in pads]
 
 
 def test_decode_bucket_hlo_holds_the_phase_scopes():
