@@ -251,11 +251,14 @@ def decode_device(
     ``FPTC_USE_KERNELS`` default; the kernel path is bit-identical to the
     XLA path).  Decode many containers at once with
     :class:`repro.serving.batch_decode.BatchDecoder` directly.
+
+    Returns a writable array the caller owns: the signal is copied out of
+    the read-only bucket view ``DecodedBatch.to_host`` hands back.
     """
     from repro.serving.batch_decode import default_decoder
 
     dec = default_decoder(use_kernels=use_kernels)
-    return dec.decode([container], tables).to_host()[0]
+    return np.array(dec.decode([container], tables).to_host()[0])
 
 
 def transcode(

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 from collections import deque
 from typing import (
     Any,
@@ -182,7 +183,9 @@ def _decode_bucket_phases(
     coding: Tuple[int, int, bool] = TRIVIAL_CODING,
     tuning_epoch: int = 0,
 ) -> jnp.ndarray:
-    """Decode one concatenated bucket to windows f32[num_windows, N].
+    """Decode one concatenated bucket to its samples f32[num_windows * N],
+    window after window: each signal's samples are one contiguous run, so
+    the drain hands strips back as slices of the 1-D host copy.
 
     Statics are *bucket shape only* — every per-container quantity rides in
     the device arrays (the symlen sidecar induces all word/symbol offsets via
@@ -241,7 +244,7 @@ def _decode_bucket_phases(
                 hi, lo, sl, tables, lut, rscale, v3,
                 l_max=l_max, max_symlen=max_symlen, num_windows=num_windows,
                 n=n, e=e, coding=coding,
-            )
+            ).reshape(-1)
     syms = symlen.unpack_symlen(
         hi, lo, sl,
         tables.dec_limit, tables.dec_first, tables.dec_rank, tables.dec_syms,
@@ -258,7 +261,7 @@ def _decode_bucket_phases(
         ).astype(jnp.int32)
     with jax.named_scope("fptc.decode.idct"):
         coeffs = lut[jnp.arange(e, dtype=jnp.int32)[None, :], levels]
-        return dct.inverse_dct(coeffs, n, scale=rscale)
+        return dct.inverse_dct(coeffs, n, scale=rscale).reshape(-1)
 
 
 _decode_bucket = functools.partial(
@@ -350,21 +353,32 @@ _decode_fixed_kernels = functools.partial(
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class _Slice:
-    """Where container i's samples live: rows [win_off, win_off + nw) of
-    group ``group``'s window tensor, first ``signal_length`` samples."""
+    """Where container i's samples live: ``signal_length`` samples from
+    ``start`` of group ``group``'s flat sample tensor."""
 
     group: int
-    win_off: int
-    num_windows: int
+    start: int
     signal_length: int
 
 
-class DecodedBatch:
-    """Result of :meth:`BatchDecoder.decode` — device-resident windows.
+_SAMPLE_BYTES = np.dtype(np.float32).itemsize  # decoded samples are f32
 
+
+class DecodedBatch:
+    """Result of :meth:`BatchDecoder.decode` — device-resident samples.
+
+    Each bucket's decode program emits its samples flat, window after
+    window, so every signal is one contiguous run of its bucket's tensor.
     ``to_host()`` performs the only host sync: every bucket's d2h copy is
     started before any is materialized (so shard drains overlap), then
-    numpy slicing back to per-container signals (input order preserved).
+    each signal is handed back, in input order, as a view of its bucket's
+    host array — no sample is copied after the d2h.
+
+    A drained signal is a float32, C-contiguous, **read-only** array of
+    shape ``(signal_length,)``.  It keeps its bucket's (padded) host array
+    alive while it is held, never overlaps another signal of the same
+    drain, and is never changed by a later decode or drain.  A caller that
+    wants to own or write a signal takes ``np.array(y)``.
 
     A quarantined decode (``BatchDecoder.decode(..., quarantine=True)``)
     carries a ``poisoned`` record per excluded signal: its slice is None,
@@ -376,21 +390,32 @@ class DecodedBatch:
     def __init__(
         self,
         groups: List[jnp.ndarray],
+        widths: List[int],
         slices: List[Optional[_Slice]],
         *,
         poisoned: Optional[Dict[int, Exception]] = None,
+        stats: Optional["BatchDecoderStats"] = None,
     ):
-        self._groups = groups  # per group: f32[num_windows_p, N] on device
+        self._groups = groups  # per group: f32[num_windows_p * N] on device
+        self._widths = widths  # per group: N, the samples of a window
         self._slices = slices
         self._poisoned: Dict[int, Exception] = dict(poisoned or {})
+        self._stats = stats  # the decoder's, counting the drained bytes
 
     def __len__(self) -> int:
         return len(self._slices)
 
     @property
-    def device_windows(self) -> List[jnp.ndarray]:
-        """The raw per-bucket window tensors (device arrays)."""
+    def device_samples(self) -> List[jnp.ndarray]:
+        """The per-bucket flat sample tensors (device arrays), padding
+        windows included: what ``to_host()`` copies."""
         return list(self._groups)
+
+    @property
+    def device_windows(self) -> List[jnp.ndarray]:
+        """The per-bucket window tensors f32[num_windows_p, N] (device
+        arrays; each a reshape of its flat tensor, made on the device)."""
+        return [g.reshape(-1, n) for g, n in zip(self._groups, self._widths)]
 
     def device_signal(self, i: int) -> jnp.ndarray:
         """Container i's reconstructed signal as a device array (lazy).
@@ -398,8 +423,7 @@ class DecodedBatch:
         s = self._slices[i]
         if s is None:
             raise self._poisoned[i]
-        rows = self._groups[s.group][s.win_off:s.win_off + s.num_windows]
-        return rows.reshape(-1)[: s.signal_length]
+        return self._groups[s.group][s.start:s.start + s.signal_length]
 
     def block_until_ready(self) -> "DecodedBatch":
         for g in self._groups:
@@ -408,20 +432,34 @@ class DecodedBatch:
 
     def to_host(self) -> List[Any]:
         """Drain the batch: one device->host transfer per bucket, all
-        copies in flight before the first materializes.  Quarantined
-        positions hold their typed per-request error instead of samples —
-        a poisoned signal never raises batch-wide here."""
+        copies in flight before the first materializes.  Each signal comes
+        back as a read-only view of its bucket's host array (the class
+        docstring states the contract); take ``np.array(y)`` to own one.
+        Quarantined positions hold their typed per-request error instead
+        of samples — a poisoned signal never raises batch-wide here."""
         with span("fptc.drain.d2h",
                   bytes=lambda: sum(g.nbytes for g in self._groups)):
             host = fetch_to_host(self._groups)
+        copied = 0
+        for g, h in enumerate(host):
+            if not h.flags.c_contiguous:
+                # a strided host copy would hand back strided strips: make
+                # the bucket contiguous once, and count the copy
+                host[g] = np.ascontiguousarray(h)
+                host[g].flags.writeable = False
+                copied += host[g].nbytes
+        nbytes = _SAMPLE_BYTES * sum(
+            s.signal_length for s in self._slices if s is not None)
         out: List[Any] = []
-        with span("fptc.drain.stitch"):
+        with span("fptc.drain.stitch", bytes=nbytes):
             for i, s in enumerate(self._slices):
                 if s is None:
                     out.append(self._poisoned[i])
                     continue
-                rows = host[s.group][s.win_off:s.win_off + s.num_windows]
-                out.append(rows.reshape(-1)[: s.signal_length].copy())
+                # a basic slice of a 1-D array: always a view, never a copy
+                out.append(host[s.group][s.start:s.start + s.signal_length])
+        if self._stats is not None:
+            self._stats.count_drain(nbytes, copied)
         return out
 
 
@@ -569,12 +607,27 @@ class BatchDecoderStats:
     plan_hits: int = 0
     plan_misses: int = 0
     quarantined: int = 0  # signals poisoned out of quarantine=True batches
+    drain_bytes: int = 0  # sample bytes handed back by to_host()
+    # host bytes to_host() copied to hand them back: a bucket whose host
+    # copy came back strided is made contiguous; 0 where strips are views
+    drain_bytes_copied: int = 0
     # per-dispatch padding/occupancy records (bounded history) — feeds the
     # bench JSON's bucket-waste report and the half-octave bucket-policy
     # decision (ROADMAP)
     bucket_pad: "deque[dict]" = dataclasses.field(
         default_factory=lambda: deque(maxlen=1024)
     )
+    # drains run on the callers' threads (the frontend's drain pool among
+    # them), so the drain counters are updated under a lock
+    _drain_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False
+    )
+
+    def count_drain(self, nbytes: int, copied: int = 0) -> None:
+        """Add one drain's handed-back and host-copied bytes."""
+        with self._drain_lock:
+            self.drain_bytes += nbytes
+            self.drain_bytes_copied += copied
 
 
 class BatchDecoder:
@@ -585,7 +638,10 @@ class BatchDecoder:
         dec = BatchDecoder()
         batch = dec.decode(containers, tables)   # tables: DomainTables, or
                                                  # {domain_id: DomainTables}
-        signals = batch.to_host()                # one sync, input order
+        signals = batch.to_host()                # one sync, input order;
+                                                 # read-only views of the
+                                                 # drained bucket arrays
+        mine = np.array(signals[0])              # a writable copy to own
 
     Containers are grouped by :attr:`Container.plan_key` (domain, config);
     each group's streams are concatenated word-wise and padded to the
@@ -769,7 +825,8 @@ class BatchDecoder:
             slices: List[Optional[_Slice]] = (
                 [None] * total if quarantine else []
             )
-            return DecodedBatch([], slices, poisoned=poisoned)
+            return DecodedBatch(
+                [], [], slices, poisoned=poisoned, stats=self.stats)
 
         if isinstance(tables, DomainTables):
             # a single DomainTables means "decode everything with these" —
@@ -820,7 +877,8 @@ class BatchDecoder:
             for j, i in enumerate(clean_pos):
                 full[i] = slices[j]
             slices = full
-        return DecodedBatch(batch._groups, slices, poisoned=poisoned)
+        return DecodedBatch(batch._groups, batch._widths, slices,
+                            poisoned=poisoned, stats=self.stats)
 
     def decode_streams(
         self,
@@ -888,7 +946,7 @@ class BatchDecoder:
             arm = "pallas" if use_kernels else "xla"
             with span("fptc.dispatch", shard=grp.shard, device=device,
                       arm=arm, words_padded=wp):
-                windows = _decode_bucket(
+                samples = _decode_bucket(
                     grp.hi,
                     grp.lo,
                     grp.symlen,
@@ -920,32 +978,33 @@ class BatchDecoder:
                 "windows": grp.total_windows,
                 "windows_padded": num_windows,
             })
-            return windows, grp
+            return samples, grp
 
         results = self.executor.run(groups, upload, dispatch)
 
         out_groups: List[jnp.ndarray] = []
+        widths: List[int] = []
         slices: List[_Slice] = []
-        for g, (windows, grp) in enumerate(results):
-            win_off = 0
+        for g, (samples, grp) in enumerate(results):
+            n = grp.plan_key[1]  # (domain_id, n, e, l_max, coding)
+            start = 0
             for num_windows, signal_length in grp.members:
                 slices.append(_Slice(
-                    group=g,
-                    win_off=win_off,
-                    num_windows=num_windows,
-                    signal_length=signal_length,
+                    group=g, start=start, signal_length=signal_length,
                 ))
-                win_off += num_windows
-            out_groups.append(windows)
+                start += num_windows * n
+            out_groups.append(samples)
+            widths.append(n)
 
         self.stats.plan_hits = self._plans.hits
         self.stats.plan_misses = self._plans.misses
-        return DecodedBatch(out_groups, slices)
+        return DecodedBatch(out_groups, widths, slices, stats=self.stats)
 
     def decode_to_host(
         self, containers: Sequence[Container], tables: TablesArg
     ) -> List[np.ndarray]:
-        """Convenience: decode + drain in one call."""
+        """Convenience: decode + drain in one call (read-only views, as
+        :meth:`DecodedBatch.to_host`)."""
         return self.decode(containers, tables).to_host()
 
 

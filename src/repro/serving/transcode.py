@@ -523,7 +523,7 @@ class Transcoder:
             # lazy container staging: shard rides the scheduler buckets
             group_shards = [b.shard for b in buckets]
 
-        # flatten each shard's decoded window tensors once (zero-padded by
+        # join each shard's flat decoded sample tensors once (zero-padded by
         # the widest bucket so every gather slice stays in bounds, then up
         # to a bucket-edge length: the flat tensor is an operand of the
         # fused gather+encode jit, so an unbucketed data-dependent length
@@ -532,7 +532,7 @@ class Transcoder:
         # log sizes) like every other traced shape in the engines);
         # per-signal sample runs are contiguous, so encode staging is one
         # batched dynamic_slice fused into each bucket's encode dispatch
-        tensors = decoded.device_windows
+        tensors = decoded.device_samples
         starts = np.zeros((len(meta),), dtype=np.int64)
         flats: Dict[int, jnp.ndarray] = {}
         remaining: Dict[int, int] = {}
@@ -558,13 +558,12 @@ class Transcoder:
                     np.float32,
                 ))
                 flats[shard] = jnp.concatenate(
-                    [tensors[g].reshape(-1) for g in gidx] + [pad]
+                    [tensors[g] for g in gidx] + [pad]
                 )
                 remaining[shard] = 0
-            widths = [w.shape[1] for w in tensors]
             for i in range(len(meta)):
                 s = decoded._slices[member_pos[i]]
-                starts[i] = bases[s.group] + s.win_off * widths[s.group]
+                starts[i] = bases[s.group] + s.start
                 remaining[shard_ids[i]] += 1
 
         def stage(idxs, kp: int, wp: int, n: int, device) -> GatherStage:

@@ -59,8 +59,23 @@ engine.TraceAnnotation = Counted
 
 one = BatchDecoder(devices=None).decode(containers, tables).to_host()
 four = BatchDecoder(devices="auto")
-got = four.decode(containers, tables).to_host()
+batch = four.decode(containers, tables)
+got = batch.to_host()
 untraced = Counted.made
+# the drain contract on four devices: read-only float32 views, bit-equal
+# to the device signals, no two sharing memory, nothing copied
+views = {
+    "device_equal": all(np.array_equal(y, np.array(batch.device_signal(i)))
+                        for i, y in enumerate(got)),
+    "layout": all(y.dtype == np.float32 and y.flags.c_contiguous
+                  and y.shape == (c.signal_length,) and not y.flags.writeable
+                  for y, c in zip(got, containers)),
+    "overlaps": sum(np.shares_memory(a, b) for i, a in enumerate(got)
+                    for b in got[i + 1:]),
+    "strip_bytes": sum(y.nbytes for y in got),
+    "drain_bytes": four.stats.drain_bytes,
+    "drain_bytes_copied": four.stats.drain_bytes_copied,
+}
 
 jax.profiler.start_trace(log_dir)
 try:
@@ -100,6 +115,7 @@ print(json.dumps({
     "traced_made": Counted.made - untraced,
     "spans": [{k: s.get(k) for k in ("shard", "device", "arm",
                                       "words_padded")} for s in spans],
+    "views": views,
 }))
 '''
 
@@ -150,3 +166,15 @@ def test_one_dispatch_span_per_dispatch_while_tracing(drained):
         (r["shard"], r["device"], r["words_padded"])
         for r in drained["second"])
     assert {s["arm"] for s in drained["spans"]} == {"xla"}  # the CPU's arm
+
+
+def test_four_device_strips_are_read_only_views_of_the_device_signals(drained):
+    v = drained["views"]
+    assert v["device_equal"] and v["layout"]
+    assert v["overlaps"] == 0
+
+
+def test_four_device_drain_counts_its_bytes_and_copies_none(drained):
+    v = drained["views"]
+    assert v["drain_bytes"] == v["strip_bytes"] > 0
+    assert v["drain_bytes_copied"] == 0

@@ -64,6 +64,19 @@ def test_span_annotates_only_while_tracing(monkeypatch, enabled):
         assert computed == []  # no stat is computed with the profiler off
 
 
+def test_stitch_span_carries_the_strips_bytes(monkeypatch):
+    monkeypatch.setattr(_Recorder, "enabled", True)
+    monkeypatch.setattr(_Recorder, "made", [])
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", _Recorder)
+    c0, tables = uniform_code_container(24, seed=1)
+    c1, _ = uniform_code_container(40, seed=2)
+    dec = BatchDecoder(devices=None)
+    out = dec.decode([c0, c1, c0], tables).to_host()
+    stitch = [st for n, st in _Recorder.made if n == "fptc.drain.stitch"]
+    assert stitch == [{"bytes": sum(y.nbytes for y in out)}]
+    assert dec.stats.drain_bytes == stitch[0]["bytes"]
+
+
 def test_span_is_a_trace_annotation_inside_a_trace(tmp_path):
     assert not isinstance(span("fptc.test"), jax.profiler.TraceAnnotation)
     jax.profiler.start_trace(str(tmp_path))
